@@ -73,29 +73,3 @@ def named_sharding(mesh, *names: Optional[str]):
     """A NamedSharding for jit in_/out_shardings from logical names."""
     return jax.sharding.NamedSharding(mesh, logical_spec(*names))
 
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` only exists on newer jax; on older releases the
-    Mesh object itself is the context manager. Use as
-    ``with set_mesh(mesh): ...``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new releases export it at
-    the top level (with ``check_vma``), 0.4.x under
-    ``jax.experimental.shard_map`` (with ``check_rep``). Replication
-    checking is disabled on both — the DDAL pod dispatch returns
-    per-device slices whose replication the checker cannot see through
-    the ``axis_index``-driven gathers."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)

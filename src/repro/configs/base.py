@@ -114,6 +114,22 @@ class ArchConfig:
     def with_(self, **kw) -> "ArchConfig":
         return replace(self, **kw)
 
+    def with_layers(self, n: int) -> "ArchConfig":
+        """The first ``n`` whole layers at the published widths — the
+        depth cut that fits a group of agents on one chip's share of
+        the model. Hybrid configs count depth in super-blocks and are
+        not cut here."""
+        if self.hybrid is not None:
+            raise ValueError(
+                f"{self.name}: a hybrid config's depth is set by its "
+                f"super-blocks, not by n_layers")
+        if not 1 <= n <= self.n_layers:
+            raise ValueError(
+                f"{self.name} has {self.n_layers} layers; cannot keep "
+                f"{n}")
+        return replace(self, n_layers=n,
+                       first_k_dense=min(self.first_k_dense, n))
+
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: ≤2 layers, d_model ≤ 512, ≤4 experts."""
         d_model = min(self.d_model, 256)

@@ -427,6 +427,12 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
     vopt = jax.vmap(opt.update, in_axes=(0, 0, 0, None))
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Any]:
+        # No parameter-sized array passes through a lax.cond: XLA copies
+        # a conditional's pass-through outputs, and at published widths
+        # those copies do not fit beside the state on one chip. The
+        # per-epoch choice (warm-up / accumulate / share) is elementwise
+        # selects instead, and only the eq. 4 combine — the one step
+        # that moves data between agents — runs under a cond.
         step = state.step
         losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(
             state.params, batch)
@@ -440,89 +446,82 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
 
         warmup = step < spec.threshold
         is_share = jnp.logical_not(warmup) & (step % spec.minibatch == 0)
+        rnd = (step + spec.minibatch - 1) // spec.minibatch
 
-        def warmup_branch(_):
-            p2, o2 = vopt(grads, state.opt_state, state.params, step)
-            if elastic:
-                p2 = _select_rows(alive, p2, state.params)
-                o2 = _select_rows(alive, o2, state.opt_state)
-            return p2, o2, know
+        # accumulate this epoch's piece into the local window (after
+        # warm-up; elastic: live agents only — dead agents' gradients
+        # are garbage, their data still flows)
+        kdt = jnp.dtype(spec.knowledge_dtype)
+        T_t = training_experience(step, spec.t_weighting)
+        acc = jnp.logical_not(warmup)
+        if elastic:
+            acc = acc & alive
 
-        def sharing_branch(_):
-            # accumulate this epoch's piece into the local window
-            kdt = jnp.dtype(spec.knowledge_dtype)
-            T_t = training_experience(step, spec.t_weighting)
-            if elastic:
-                # dead agents' gradients are garbage (their data still
-                # flows): hold their rows instead of accumulating
-                def row_gate(x):
-                    return jnp.reshape(alive,
-                                       (-1,) + (1,) * (x.ndim - 1))
-                tg = tree_map(
-                    lambda a, g: jnp.where(
-                        row_gate(a),
-                        a + (T_t * g.astype(jnp.float32)).astype(kdt),
-                        a),
-                    know.tg, grads)
-                rg = tree_map(
-                    lambda a, g: jnp.where(row_gate(a),
-                                           a + g.astype(kdt), a),
-                    know.rg, grads)
-                tsum = know.tsum + jnp.where(alive, T_t, 0.0)
-                rsum = know.rsum + jnp.where(alive, 1.0, 0.0)
-            else:
-                tg = tree_map(
-                    lambda a, g: a + (T_t * g.astype(jnp.float32)
-                                      ).astype(kdt),
-                    know.tg, grads)
-                rg = tree_map(lambda a, g: a + g.astype(kdt),
-                              know.rg, grads)
-                tsum = know.tsum + T_t
-                rsum = know.rsum + 1.0
-            sk = know.sk
-            if sketch_dim > 0:
-                # carry the window sketch: one streaming projection of
-                # this epoch's grads, added to the (A, d) running sum.
-                # The projection is linear and every step of the window
-                # ending at share step t folds the same round index
-                # ((step + mb − 1) // mb), so at share time sk IS the
-                # sketch of rg — nothing parameter-sized is re-read.
-                rnd = (step + spec.minibatch - 1) // spec.minibatch
+        def row_gate(x):
+            return jnp.reshape(acc, (-1,) + (1,) * (x.ndim - 1))
+        tg = tree_map(
+            lambda a, g: jnp.where(
+                row_gate(a),
+                a + (T_t * g.astype(jnp.float32)).astype(kdt), a),
+            know.tg, grads)
+        rg = tree_map(
+            lambda a, g: jnp.where(row_gate(a), a + g.astype(kdt), a),
+            know.rg, grads)
+        tsum = know.tsum + jnp.where(acc, T_t, 0.0)
+        rsum = know.rsum + jnp.where(acc, 1.0, 0.0)
+        sk = know.sk
+        if sketch_dim > 0:
+            # carry the window sketch: one streaming projection of
+            # this epoch's grads, added to the (A, d) running sum.
+            # The projection is linear and every step of the window
+            # ending at share step t folds the same round index
+            # ((step + mb − 1) // mb), so at share time sk IS the
+            # sketch of rg — nothing parameter-sized is re-read.
+            def add_sketch(_):
                 contrib = exchange.sketch_step(grads, rnd)
                 if elastic:
                     contrib = jnp.where(alive[:, None], contrib, 0.0)
-                sk = know.sk + contrib
-            k2 = Knowledge(tg=tg, tsum=tsum, rg=rg, rsum=rsum,
-                           rel=know.rel, sk=sk, alive=know.alive)
+                return know.sk + contrib
 
-            def do_share(_):
-                # window-accumulated grads are already a temporal
-                # average over the share window — the estimator
-                # observes them (or the carried (A, d) sketch, so only
-                # sketch rows — never parameter planes — cross the
-                # mesh for relevance), then the combiner strategy runs
-                # eq. 4.
-                rel = exchange.observe(
-                    k2.rel, grads=k2.rg, sketch=k2.sk,
-                    rnd=(step + spec.minibatch - 1) // spec.minibatch,
-                    alive=alive)
-                gbar = exchange.combine(k2, rel, step, alive=alive)
-                p2, o2 = vopt(gbar, state.opt_state, state.params, step)
-                if elastic:
-                    p2 = _select_rows(alive, p2, state.params)
-                    o2 = _select_rows(alive, o2, state.opt_state)
-                return p2, o2, init_knowledge(state.params, kdt,
-                                              rel=rel,
-                                              sketch_dim=sketch_dim,
-                                              alive=know.alive)
+            sk = jax.lax.cond(warmup, lambda _: know.sk, add_sketch,
+                              None)
+        k2 = Knowledge(tg=tg, tsum=tsum, rg=rg, rsum=rsum,
+                       rel=know.rel, sk=sk, alive=know.alive)
 
-            def hold(_):
-                return state.params, state.opt_state, k2
+        def do_share(_):
+            # window-accumulated grads are already a temporal average
+            # over the share window — the estimator observes them (or
+            # the carried (A, d) sketch, so only sketch rows — never
+            # parameter planes — cross the mesh for relevance), then
+            # the combiner strategy runs eq. 4.
+            rel = exchange.observe(k2.rel, grads=k2.rg, sketch=k2.sk,
+                                   rnd=rnd, alive=alive)
+            return exchange.combine(k2, rel, step, alive=alive), rel
 
-            return jax.lax.cond(is_share, do_share, hold, None)
+        gbar_shape = jax.eval_shape(do_share, None)[0]
 
-        params, opt_state, know = jax.lax.cond(
-            warmup, warmup_branch, sharing_branch, None)
+        def no_share(_):
+            return (tree_map(lambda x: jnp.zeros(x.shape, x.dtype),
+                             gbar_shape), k2.rel)
+
+        gbar, rel = jax.lax.cond(is_share, do_share, no_share, None)
+
+        # warm-up steps apply the local gradient, share steps ḡ, and
+        # the steps in between only accumulate
+        g_apply = tree_map(lambda g, gb: jnp.where(is_share, gb, g),
+                           grads, gbar)
+        p2, o2 = vopt(g_apply, state.opt_state, state.params, step)
+        update = warmup | is_share
+        if elastic:
+            update = update & alive
+        params = _select_rows(update, p2, state.params)
+        opt_state = _select_rows(update, o2, state.opt_state)
+        def reset(x):                  # a share step empties the window
+            return jnp.where(is_share, jnp.zeros_like(x), x)
+        know = k2._replace(
+            tg=tree_map(reset, k2.tg), tsum=reset(k2.tsum),
+            rg=tree_map(reset, k2.rg), rsum=reset(k2.rsum), rel=rel,
+            sk=None if sk is None else reset(sk))
         metrics = {"loss": losses, "step": step,
                    "shared": is_share.astype(jnp.int32)}
         new_state = TrainState(params=params, opt_state=opt_state,

@@ -118,22 +118,27 @@ def _fused_wavg_kernel(T_ref, R_ref, V_ref, g_ref, o_ref, ws_ref, *,
 def _fused_wavg_q_kernel(T_ref, R_ref, V_ref, q_ref, s_ref, o_ref,
                          ws_ref, *, eps, q_rows):
     """Quantized planes: q_ref (m, 1, ROWS, LANES) int8, s_ref
-    (m, 1, ROWS // q_rows) fp32 per-block scales — dequantised inside
-    the block loop (one int8 HBM pass, never an fp32 copy of G)."""
+    (1, m, ROWS // q_rows) fp32 per-block scales — dequantised inside
+    the block loop (one int8 HBM pass, never an fp32 copy of G). Each
+    scale block is ``q_rows`` whole sublane rows, so the dequant is a
+    static row slice times a broadcast scalar."""
     m, _, rows, lanes = q_ref.shape
     nb = rows // q_rows
     w = _eq4_weights_block(T_ref[...], R_ref[...], V_ref[...], eps)
+    sc = s_ref[0]                                        # (m, nb)
 
     @pl.when(pl.program_id(0) == 0)
     def _():
         ws_ref[...] = jnp.sum(w).reshape(1, 1)
 
-    acc = jnp.zeros((nb, q_rows, lanes), jnp.float32)
+    acc = [jnp.zeros((q_rows, lanes), jnp.float32) for _ in range(nb)]
     for j in range(m):
-        qf = q_ref[j].astype(jnp.float32).reshape(nb, q_rows, lanes)
-        sc = s_ref[j].reshape(nb, 1, 1)      # broadcast over the block
-        acc = acc + w[j, 0] * (qf * sc)
-    o_ref[...] = acc.reshape(1, rows, lanes)
+        qf = q_ref[j, 0].astype(jnp.float32)             # (rows, lanes)
+        for b in range(nb):
+            blk = qf[b * q_rows:(b + 1) * q_rows]
+            acc[b] = acc[b] + w[j, 0] * (blk * sc[j, b])
+    for b in range(nb):
+        o_ref[0, b * q_rows:(b + 1) * q_rows, :] = acc[b]
 
 
 def _fused_call(kernel, extra_in, extra_specs, T, R, valid, tiles,
@@ -205,12 +210,14 @@ def fused_wavg_q_flat(Q, scale, T, R, valid, q_block: int,
     q_rows = q_block // LANES
     nb_tile = rows // q_rows
     Q4 = Q.reshape(m, tiles, rows, LANES)
-    S3 = scale.reshape(m, tiles, nb_tile)
+    # tile-major scales: each grid step's (m, nb_tile) block spans the
+    # array's last two dims whole, as the TPU block tiling requires
+    S3 = scale.reshape(m, tiles, nb_tile).transpose(1, 0, 2)
     out, wsum = _fused_call(
         functools.partial(_fused_wavg_q_kernel, eps=eps,
                           q_rows=q_rows),
         [Q4, S3],
         [pl.BlockSpec((m, 1, rows, LANES), lambda i: (0, i, 0, 0)),
-         pl.BlockSpec((m, 1, nb_tile), lambda i: (0, i, 0))],
+         pl.BlockSpec((1, m, nb_tile), lambda i: (i, 0, 0))],
         T, R, valid, tiles, rows, m, interpret)
     return out.reshape(n_pad)[:n], wsum

@@ -16,7 +16,10 @@ their ``_q`` quantized twins) take the raw (T, R, valid) metadata and
 emit (ḡ, Σw) in one pass. They carry a grad_sketch-style ``impl``
 knob:
 
-* ``"auto"``   — Pallas on TPU, tiled XLA elsewhere;
+* ``"auto"``   — Pallas where the program is lowered for a TPU, tiled
+  XLA elsewhere (``jax.lax.platform_dependent``: the choice follows
+  the device the step is compiled for, not the process's default
+  backend, so a CPU reference run beside a chip takes the XLA form);
 * ``"pallas"`` — the fused kernel (``interpret`` then auto-resolves
   via :func:`resolve_interpret` unless forced);
 * ``"xla"``    — portable path. At quantization-off this is literally
@@ -52,15 +55,21 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
 
 
 def resolve_impl(impl: Optional[str]) -> str:
-    """``auto``/None → ``pallas`` on TPU else ``xla``; others →
-    themselves."""
+    """Validate an ``impl`` name; None means ``auto``."""
     if impl is None:
         impl = "auto"
     if impl not in IMPLS:
         raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
+
+
+def _pick(kind: str, pallas, xla):
+    """The function ``kind`` names; ``auto`` defers the choice to
+    lowering, where the target platform is known."""
+    if kind == "auto":
+        return lambda *a: jax.lax.platform_dependent(*a, tpu=pallas,
+                                                     default=xla)
+    return pallas if kind == "pallas" else xla
 
 
 def wavg(G: jnp.ndarray, w: jnp.ndarray, *,
@@ -91,11 +100,11 @@ def fused_wavg(G, T, R, valid, *, impl: str = "auto",
                interpret: Optional[bool] = None, eps: float = EQ4_EPS):
     """Fused eq. 4 on a flat plane stack G: (m, N) → (ḡ: (N,), Σw)."""
     kind = resolve_impl(impl)
-    if kind == "xla":
-        return ref.fused_wavg(G, T, R, valid, eps=eps)
-    return fused_wavg_flat(G, T, R, valid,
-                           interpret=resolve_interpret(interpret),
-                           eps=eps)
+    interp = kind == "pallas" and resolve_interpret(interpret)
+    return _pick(
+        kind,
+        lambda *a: fused_wavg_flat(*a, interpret=interp, eps=eps),
+        lambda *a: ref.fused_wavg(*a, eps=eps))(G, T, R, valid)
 
 
 def _xla_fused_wavg_q_flat(Q, scale, w, q_block: int):
@@ -130,12 +139,17 @@ def fused_wavg_q(Q, scale, T, R, valid, q_block: int, *,
                  eps: float = EQ4_EPS):
     """Fused eq. 4 over int8 block-quantized planes → (ḡ, Σw)."""
     kind = resolve_impl(impl)
-    if kind == "xla":
+    interp = kind == "pallas" and resolve_interpret(interpret)
+
+    def xla(Q, scale, T, R, valid):
         w = eq4_weights(T, R, valid, eps=eps)
         return _xla_fused_wavg_q_flat(Q, scale, w, q_block), jnp.sum(w)
-    return fused_wavg_q_flat(Q, scale, T, R, valid, q_block,
-                             interpret=resolve_interpret(interpret),
-                             eps=eps)
+
+    return _pick(
+        kind,
+        lambda *a: fused_wavg_q_flat(*a, q_block, interpret=interp,
+                                     eps=eps),
+        xla)(Q, scale, T, R, valid)
 
 
 def tree_fused_wavg(stacked, T, R, valid, *, impl: str = "auto",
@@ -149,22 +163,21 @@ def tree_fused_wavg(stacked, T, R, valid, *, impl: str = "auto",
     fused kernel and keeps small leaves on the oracle contraction."""
     kind = resolve_impl(impl)
     w = eq4_weights(T, R, valid, eps=eps)
-    if kind == "xla":
-        g = jax.tree.map(
-            lambda x: jnp.tensordot(w.astype(x.dtype), x, axes=(0, 0)),
-            stacked)
-        return g, jnp.sum(w)
+    interp = kind == "pallas" and resolve_interpret(interpret)
 
-    interp = resolve_interpret(interpret)
+    def xla(x):
+        return jnp.tensordot(w.astype(x.dtype), x, axes=(0, 0))
 
-    def leaf(x):
+    def kernel(x):
         m = x.shape[0]
-        size = int(x.size) // m
-        if size < _MIN_KERNEL_SIZE:
-            return jnp.tensordot(w.astype(x.dtype), x, axes=(0, 0))
-        g, _ = fused_wavg_flat(x.reshape(m, size), T, R, valid,
+        g, _ = fused_wavg_flat(x.reshape(m, -1), T, R, valid,
                                interpret=interp, eps=eps)
         return g.reshape(x.shape[1:])
+
+    def leaf(x):
+        if int(x.size) // x.shape[0] < _MIN_KERNEL_SIZE:
+            return xla(x)
+        return _pick(kind, kernel, xla)(x)
     return jax.tree.map(leaf, stacked), jnp.sum(w)
 
 
@@ -175,7 +188,11 @@ def tree_fused_wavg_q(qtree, stree, T, R, valid, q_block: int, *,
     """Fused eq. 4 over an int8-quantized stacked pytree → (ḡ, Σw)."""
     kind = resolve_impl(impl)
     w = eq4_weights(T, R, valid, eps=eps)
-    interp = resolve_interpret(interpret)
+    interp = kind == "pallas" and resolve_interpret(interpret)
+
+    def kernel(qf, sf):
+        return fused_wavg_q_flat(qf, sf, T, R, valid, q_block,
+                                 interpret=interp, eps=eps)[0]
 
     def leaf(q, s):
         m = q.shape[0]
@@ -186,11 +203,10 @@ def tree_fused_wavg_q(qtree, stree, T, R, valid, q_block: int, *,
             g = jnp.tensordot(w.astype(jnp.float32),
                               ref.dequantize_flat(qf, sf, q_block),
                               axes=(0, 0))
-        elif kind == "xla":
-            g = _xla_fused_wavg_q_flat(qf, sf, w, q_block)
         else:
-            g, _ = fused_wavg_q_flat(qf, sf, T, R, valid, q_block,
-                                     interpret=interp, eps=eps)
+            g = _pick(kind, kernel,
+                      lambda qf, sf: _xla_fused_wavg_q_flat(
+                          qf, sf, w, q_block))(qf, sf)
         return g.reshape(q.shape[1:])
     return jax.tree.map(leaf, qtree, stree), jnp.sum(w)
 

@@ -66,8 +66,9 @@ def sign_block(seed, start, count: int, dim: int) -> jnp.ndarray:
     xorshift-multiply integer hash. ``seed``/``start`` may be traced
     scalars; ``count``/``dim`` are static.
     """
-    return 1.0 - 2.0 * _sign_bits(seed, start, count, dim).astype(
-        jnp.float32)
+    # through int32: the TPU compiler has no uint32 -> float32 cast
+    bits = _sign_bits(seed, start, count, dim).astype(jnp.int32)
+    return 1.0 - 2.0 * bits.astype(jnp.float32)
 
 
 def sign_block_i8(seed, start, count: int, dim: int) -> jnp.ndarray:

@@ -7,8 +7,9 @@ projecting the flat concatenation — which is never materialised.
 
 Implementation selection (``impl``):
 
-* ``"auto"``    — Pallas kernel on TPU (one HBM pass, signs
-  regenerated in VMEM), tiled XLA elsewhere. The CPU/GPU tiled path
+* ``"auto"``    — Pallas kernel where the program is lowered for a
+  TPU (one HBM pass, signs regenerated in VMEM), tiled XLA elsewhere
+  (``jax.lax.platform_dependent``). The CPU/GPU tiled path
   is the same algorithm at XLA level: per-leaf chunks of
   ``block`` positions, one (block, d) sign block live at a time.
 * ``"pallas"`` / ``"pallas_interpret"`` — force the kernel
@@ -45,15 +46,6 @@ DEFAULT_BLOCK = 4096
 _MAX_UNROLL = 64
 
 IMPLS = ("auto", "pallas", "pallas_interpret", "xla")
-
-
-def _resolve(impl: str) -> str:
-    if impl not in IMPLS:
-        raise ValueError(f"unknown sketch impl {impl!r}; expected one "
-                         f"of {IMPLS}")
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return impl
 
 
 def _xla_sketch_flat(G: jnp.ndarray, seed, dim: int, offset: int = 0,
@@ -100,16 +92,27 @@ def _xla_sketch_flat(G: jnp.ndarray, seed, dim: int, offset: int = 0,
 def sketch_leaf(x: jnp.ndarray, seed, dim: int, offset: int = 0, *,
                 impl: str = "auto") -> jnp.ndarray:
     """One leaf (n, *param) → its (n, d) sketch contribution."""
+    if impl not in IMPLS:
+        raise ValueError(f"unknown sketch impl {impl!r}; expected one "
+                         f"of {IMPLS}")
     n = x.shape[0]
     p = int(x.size) // n
     G = jnp.reshape(x, (n, p))
-    mode = _resolve(impl)
     if p < _MIN_KERNEL_SIZE:
         return ref.sketch_flat(G, seed, dim, offset=offset)
-    if mode.startswith("pallas") and dim % LANES == 0:
+
+    def xla(G):
+        return _xla_sketch_flat(G, seed, dim, offset=offset)
+
+    def kernel(G):
         return sketch_flat(G, seed, dim, offset=offset,
-                           interpret=mode == "pallas_interpret")
-    return _xla_sketch_flat(G, seed, dim, offset=offset)
+                           interpret=impl == "pallas_interpret")
+
+    if impl == "xla" or dim % LANES:
+        return xla(G)
+    if impl == "auto":
+        return jax.lax.platform_dependent(G, tpu=kernel, default=xla)
+    return kernel(G)
 
 
 def sketch_pytree(grads, seed, dim: int, *,

@@ -17,7 +17,7 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.common.sharding import axis_rules, set_mesh
+from repro.common.sharding import axis_rules
 from repro.configs import arch_for_shape, get_arch_config
 from repro.configs.base import INPUT_SHAPES, ArchConfig, GroupSpec, ShapeConfig
 from repro.core.sharded_ddal import make_group_train_step, train_state_specs
@@ -129,7 +129,7 @@ def lower_train(cfg: ArchConfig, shape: ShapeConfig, mesh,
 
     in_shardings = (_named(mesh, state_specs, state_shapes),
                     _named(mesh, bspecs, batch_shapes))
-    with set_mesh(mesh), axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         lowered = jax.jit(step_fn, in_shardings=in_shardings).lower(
             state_shapes, batch_shapes)
     return lowered
@@ -152,7 +152,7 @@ def lower_prefill(cfg: ArchConfig, shape: ShapeConfig, mesh):
     bspecs = batch_partition_specs(cfg, shape, batch_axes)
     in_shardings = (_named(mesh, pspecs, pshapes),
                     _named(mesh, bspecs, bshapes))
-    with set_mesh(mesh), axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         lowered = jax.jit(prefill_step, in_shardings=in_shardings
                           ).lower(pshapes, bshapes)
     return lowered
@@ -176,7 +176,7 @@ def lower_decode(cfg: ArchConfig, shape: ShapeConfig, mesh):
     in_shardings = (_named(mesh, pspecs, pshapes),
                     _named(mesh, bspecs, bshapes),
                     _named(mesh, cspecs, cshapes))
-    with set_mesh(mesh), axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         lowered = jax.jit(decode_step, in_shardings=in_shardings
                           ).lower(pshapes, bshapes, cshapes)
     return lowered

@@ -6,6 +6,16 @@ state (the dry-run sets XLA_FLAGS before any jax initialisation).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code
+    places arrays with ``with_sharding_constraint`` and lets GSPMD
+    propagate the rest, which ``Explicit`` axes (jax's default) turn
+    into hard asserts."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     one GARL agent per pod (DESIGN.md §3)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(shape=(1, 1), axes=("data", "model")):
-    """Tiny mesh over however many (CPU) devices exist — tests only."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_pod_mesh(n_pods: int, devices_per_pod: int = None,
@@ -40,8 +45,7 @@ def make_pod_mesh(n_pods: int, devices_per_pod: int = None,
                 f"{n_dev} devices do not split into {n_pods} pods — "
                 f"pass devices_per_pod explicitly")
         devices_per_pod = n_dev // n_pods
-    return jax.make_mesh((n_pods, devices_per_pod),
-                         (pod_axis, "agent"))
+    return make_mesh((n_pods, devices_per_pod), (pod_axis, "agent"))
 
 
 def train_rules(mesh, pod_axis: str = "pod") -> dict:

@@ -1,5 +1,7 @@
 """Serving launcher: batched / continuous / multi-tenant group serving
-for any model-zoo arch.
+for any model-zoo arch. ``--full`` serves the published widths,
+``--layers N`` their first N whole layers; ``main(argv)`` returns the
+generated tokens by request id.
 
 Serving configuration rides one generic ``--serve key=value`` escape
 hatch whose vocabulary derives from ``repro.serving.cli_options()``
@@ -64,9 +66,16 @@ def main(argv=None):
                    help="group engine: restore the published param "
                         "planes from a ParamStore checkpoint instead "
                         "of random init")
-    p.add_argument("--full", action="store_true")
+    p.add_argument("--full", action="store_true",
+                   help="published widths and depth of --arch instead "
+                        "of the reduced smoke config")
+    p.add_argument("--layers", type=int, default=None,
+                   help="with --full: keep the first N whole layers "
+                        "(widths stay published)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    if args.layers is not None and not args.full:
+        p.error("--layers cuts the depth of the --full config")
 
     import jax
     import numpy as np
@@ -99,6 +108,8 @@ def main(argv=None):
     cfg = get_arch_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
+    elif args.layers is not None:
+        cfg = cfg.with_layers(args.layers)
     model = get_model(cfg)
 
     rng = np.random.default_rng(args.seed)
@@ -108,13 +119,16 @@ def main(argv=None):
 
     t0 = time.time()
     n_out = 0
+    results = {}
     if knobs["engine"] == "group":
         A = knobs["agents"]
         if args.ckpt:
             template = jax.eval_shape(
                 lambda ks: jax.vmap(lambda k: model.init(cfg, k))(ks),
                 jax.random.split(jax.random.PRNGKey(0), A))
-            store = ParamStore.load(args.ckpt, template)
+            # placed once on the device, not shipped with every step
+            store = ParamStore.load(args.ckpt, template,
+                                    placer=jax.device_put)
             print(f"restored planes v{store.version} from {args.ckpt}")
         else:
             keys = jax.random.split(jax.random.PRNGKey(args.seed), A)
@@ -129,6 +143,7 @@ def main(argv=None):
         reqs = [GroupRequest(rid, rid % A, pr)
                 for rid, pr in enumerate(prompts)]
         out = engine.run(reqs)
+        results = out
         for req in reqs:
             toks = out[req.rid]
             n_out += len(toks)
@@ -146,6 +161,7 @@ def main(argv=None):
                                     batch_size=knobs["slots"],
                                     prompt_pad=knobs["prompt_pad"])
         out = batcher.run(prompts)
+        results = {rid: list(out[rid]) for rid in range(len(prompts))}
         for rid, pr in enumerate(prompts):
             n_out += len(out[rid])
             print(f"req {rid}: prompt={np.asarray(pr)} "
@@ -158,13 +174,19 @@ def main(argv=None):
             out = engine.generate(toks, lens, jax.random.PRNGKey(bi))
             n_out += out.shape[0] * out.shape[1]
             for row in range(out.shape[0]):
+                rid = bi * knobs["slots"] + row
+                if rid < len(prompts):          # not tail padding
+                    results[rid] = [int(t) for t in out[row]]
                 print(f"batch {bi} slot {row}: "
                       f"prompt={np.asarray(toks[row][:int(lens[row])])} "
                       f"-> {np.asarray(out[row])}")
     dt = time.time() - t0
     print(f"{n_out} tokens in {dt:.1f}s ({n_out / dt:,.0f} tok/s, "
           f"incl. compile)")
+    return results
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,19 +1,32 @@
 """Training launcher: DDAL group-agent training of any model-zoo arch.
 
-On the CPU rig this runs REDUCED configs end-to-end (real data → real
-gradients → eq. 4 knowledge exchange → optimiser); on a TPU pod the
-same code path runs the full config over the production mesh
-(--mesh prod / prod-multipod).
+Without ``--full`` this runs the REDUCED smoke config end-to-end (real
+data → real gradients → eq. 4 knowledge exchange → optimiser). With
+``--full`` it runs the published widths; ``--layers N`` keeps the
+first N whole layers, which is how a group of agents at published
+widths fits on one chip. ``--mesh prod`` / ``prod-multipod`` place the
+full config on the 256- and 512-chip production meshes.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
         --agents 2 --steps 30 --batch 4 --seq 128 --threshold 5 \
-        --minibatch 5 [--full] [--ckpt out.npz]
+        --minibatch 5 [--full [--layers N]] [--ckpt out.npz]
+
+``main(argv)`` returns a :class:`TrainRun`: one metrics dict per step
+and the compiled step, so a caller in the same process can check them.
 """
 from __future__ import annotations
 
 import argparse
 import time
 import warnings
+from typing import Any, List, NamedTuple
+
+
+class TrainRun(NamedTuple):
+    steps: List[dict]     # per step: loss (A,) array, shared, seconds
+    compile_s: float      # lowering + compiling the step
+    compiled: Any         # the compiled step (``as_text()``, memory)
+    params_per_agent: int
 
 
 # Legacy named flags are kept as thin shims over the --exchange
@@ -160,15 +173,20 @@ def main(argv=None):
                         + _DEPRECATION.format(
                             key="relevance_sketch_dim"))
     p.add_argument("--full", action="store_true",
-                   help="full (not reduced) config — TPU pods only")
-    p.add_argument("--mesh", default="cpu",
-                   choices=["cpu", "prod", "prod-multipod", "pods"],
-                   help="'pods' builds the two-level (pod, agent) "
-                        "mesh over the visible devices (simulate with "
-                        "XLA_FLAGS=--xla_force_host_platform_"
-                        "device_count=N) and runs the pod-dispatched "
-                        "combine collectives; 'cpu' with --pods runs "
-                        "the same decomposition without collectives")
+                   help="published widths and depth of --arch instead "
+                        "of the reduced smoke config")
+    p.add_argument("--layers", type=int, default=None,
+                   help="with --full: keep the first N whole layers "
+                        "(widths stay published)")
+    p.add_argument("--mesh", default="single",
+                   choices=["single", "prod", "prod-multipod", "pods"],
+                   help="'single' runs on the default device; 'pods' "
+                        "builds the two-level (pod, agent) mesh over "
+                        "the visible devices (simulate with XLA_FLAGS="
+                        "--xla_force_host_platform_device_count=N) and "
+                        "runs the pod-dispatched combine collectives; "
+                        "'single' with --pods runs the same "
+                        "decomposition without collectives")
     p.add_argument("--elastic", action="store_true",
                    help="elastic group membership: carry a per-agent "
                         "alive mask through the exchange so agents "
@@ -190,12 +208,14 @@ def main(argv=None):
                         "keep their freshly initialised values)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    if args.layers is not None and not args.full:
+        p.error("--layers cuts the depth of the --full config")
 
     import jax
+    import numpy as np
 
     from repro import optim
     from repro.checkpoint import save
-    from repro.common.sharding import set_mesh
     from repro.configs import get_arch_config
     from repro.configs.base import GroupSpec, ShapeConfig
     from repro.core import init_train_state, make_group_train_step
@@ -205,6 +225,8 @@ def main(argv=None):
     cfg = get_arch_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
+    elif args.layers is not None:
+        cfg = cfg.with_layers(args.layers)
     # legacy named flags first (deprecation-warned when explicit),
     # --exchange key=value pairs layered on top (later spellings win)
     # — both feed the same GroupSpec fields
@@ -227,10 +249,10 @@ def main(argv=None):
             raise SystemExit("--mesh pods needs --pods >= 1 (or "
                              "--exchange pods=N)")
         mesh = make_pod_mesh(spec.pods, pod_axis=spec.pod_axis)
-        ctx = set_mesh(mesh)
-    elif args.mesh != "cpu":
+        ctx = jax.set_mesh(mesh)
+    elif args.mesh != "single":
         mesh = make_production_mesh(multi_pod=args.mesh == "prod-multipod")
-        ctx = set_mesh(mesh)
+        ctx = jax.set_mesh(mesh)
     else:
         import contextlib
         ctx = contextlib.nullcontext()
@@ -251,30 +273,48 @@ def main(argv=None):
         if mesh is not None:
             from repro.launch.shardings import agent_sharded_state
             state = agent_sharded_state(state, mesh, spec.pod_axis)
+        # the state is donated: a step never holds two copies of the
+        # params, optimiser moments and exchange window at once
         step_fn = jax.jit(make_group_train_step(cfg, spec, opt,
-                                                exchange=exchange))
+                                                exchange=exchange),
+                          donate_argnums=0)
         n_params = sum(int(x.size) for x in
                        jax.tree.leaves(state.params)) // args.agents
         print(f"arch={args.arch} reduced={not args.full} "
-              f"params/agent={n_params:,} agents={args.agents}")
-        t0 = time.time()
+              f"layers={cfg.n_layers} params/agent={n_params:,} "
+              f"agents={args.agents}")
+        t0 = time.perf_counter()
+        compiled = step_fn.lower(
+            state, make_group_batch(cfg, shape, stream, args.agents, 0)
+        ).compile()
+        compile_s = time.perf_counter() - t0
+        print(f"compiled the step in {compile_s:.1f}s")
+        steps = []
         for i in range(args.steps):
+            t0 = time.perf_counter()
             batch = make_group_batch(cfg, shape, stream, args.agents, i)
-            state, m = step_fn(state, batch)
+            state, m = compiled(state, batch)
+            m = jax.device_get(m)
+            dt = time.perf_counter() - t0
+            steps.append({"loss": np.asarray(m["loss"]),
+                          "shared": bool(m["shared"]), "seconds": dt})
             losses = " ".join(f"{float(l):6.3f}" for l in m["loss"])
-            tag = " <shared>" if int(m["shared"]) else ""
-            print(f"step {i:4d} losses [{losses}]{tag}")
-        dt = time.time() - t0
+            tag = " <shared>" if steps[-1]["shared"] else ""
+            print(f"step {i:4d} losses [{losses}] {dt * 1e3:.1f}ms{tag}")
+        dt = sum(st["seconds"] for st in steps)
         toks = args.steps * args.agents * args.batch * args.seq
         print(f"{args.steps} steps in {dt:.1f}s "
-              f"({toks / dt:,.0f} tokens/s)")
+              f"({toks / max(dt, 1e-9):,.0f} tokens/s)")
         if args.ckpt:
             save(args.ckpt, state.params, step=args.steps)
             print(f"saved params to {args.ckpt}")
         if args.ckpt_full:
             save(args.ckpt_full, state, step=int(state.step))
             print(f"saved full TrainState to {args.ckpt_full}")
+    return TrainRun(steps, compile_s, compiled, n_params)
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -179,11 +179,7 @@ def _expert_axis():
     axis = rules.get("experts")
     if axis is None:
         return None
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:                                    # noqa: BLE001
-        return None
-    if mesh is None or axis not in getattr(mesh, "axis_names", ()):
+    if axis not in jax.sharding.get_abstract_mesh().axis_names:
         return None
     return axis
 

@@ -27,7 +27,10 @@ def _segsum_mask(dA_cs):
     L = dA_cs.shape[-1]
     diff = dA_cs[..., :, None] - dA_cs[..., None, :]
     causal = jnp.tril(jnp.ones((L, L), bool))
-    return jnp.where(causal, jnp.exp(diff), 0.0)
+    # mask before the exp: above the diagonal diff is positive and, over
+    # a 256-step chunk, overflows to inf, whose gradient through a
+    # where-after-exp is 0 · inf = NaN
+    return jnp.exp(jnp.where(causal, diff, -jnp.inf))
 
 
 def ssd_chunked(x, dt, A, B, C, chunk: int,

@@ -2,6 +2,7 @@ import os
 import subprocess
 import sys
 
+import hypothesis
 import jax
 import pytest
 
@@ -17,8 +18,7 @@ jax.config.update("jax_enable_x64", False)
 # is a no-op and the test runs inline. Otherwise the fixture re-execs
 # just that test in a subprocess with the flag set — the only way to
 # get the flag in front of the jax import — and reports the child's
-# verdict. Plain subprocess + pytest: no hypothesis / pytest-cov
-# needed on local rigs.
+# verdict.
 # ---------------------------------------------------------------------
 MULTI_DEVICE_COUNT = 8
 _CHILD_ENV = "REPRO_MULTI_DEVICE_CHILD"
@@ -41,6 +41,9 @@ def multi_device(request):
         + f" --xla_force_host_platform_device_count={MULTI_DEVICE_COUNT}"
     ).strip()
     env[_CHILD_ENV] = "1"
+    # the parent may sit on a machine with an accelerator it already
+    # holds; the simulated devices live on the host platform
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_REPO_ROOT, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
@@ -57,106 +60,14 @@ def multi_device(request):
     pytest.skip(f"passed under re-exec with {MULTI_DEVICE_COUNT} "
                 f"simulated devices")
 
-# ---------------------------------------------------------------------
-# hypothesis fallback: CI installs the real package (pyproject.toml
-# [dev] extra); on bare rigs without it we register a minimal shim so
-# the property tests still run — deterministic seeded random sampling
-# instead of real shrinking/coverage. Must happen before test modules
-# import `hypothesis`, which is why it lives in conftest.
-# ---------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
 
-    # Bounded CI profile: per-test @settings(max_examples=...) caps are
-    # tuned for thoroughness; the CI fast lane trades examples for wall
-    # time so the whole lane stays inside its ~5 min budget. deadline
-    # is off in both profiles — first-call jit compilation blows any
-    # per-example deadline.
-    hypothesis.settings.register_profile(
-        "ci", max_examples=10, deadline=None, derandomize=True)
-    hypothesis.settings.register_profile(
-        "dev", max_examples=40, deadline=None)
-    hypothesis.settings.load_profile(
-        "ci" if os.environ.get("CI") else "dev")
-except ImportError:
-    import functools
-    import inspect
-    import random
-    import sys
-    import types
-    import zlib
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def _integers(min_value, max_value):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _floats(min_value=0.0, max_value=1.0, allow_nan=False,
-                allow_infinity=False, **_kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def _booleans():
-        return _Strategy(lambda rng: bool(rng.getrandbits(1)))
-
-    def _sampled_from(seq):
-        items = list(seq)
-        return _Strategy(lambda rng: items[rng.randrange(len(items))])
-
-    def _lists(elements, min_size=0, max_size=10):
-        def draw(rng):
-            size = rng.randint(min_size, max_size)
-            return [elements.draw(rng) for _ in range(size)]
-        return _Strategy(draw)
-
-    def _tuples(*elems):
-        return _Strategy(lambda rng: tuple(e.draw(rng) for e in elems))
-
-    def _settings(max_examples=100, deadline=None, **_kw):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    # profile API used by this conftest's real-hypothesis branch;
-    # harmless no-ops under the shim
-    _settings.register_profile = lambda *a, **k: None
-    _settings.load_profile = lambda *a, **k: None
-
-    def _given(*strats, **kwstrats):
-        def deco(fn):
-            @functools.wraps(fn)
-            def runner():
-                # mirror the real profiles: bounded on CI, fuller on dev
-                default_n = 15 if os.environ.get("CI") else 40
-                n = getattr(fn, "_shim_max_examples", default_n)
-                rng = random.Random(zlib.crc32(fn.__name__.encode()))
-                for _ in range(n):
-                    args = [s.draw(rng) for s in strats]
-                    kwargs = {k: s.draw(rng)
-                              for k, s in kwstrats.items()}
-                    fn(*args, **kwargs)
-            # hide the wrapped signature so pytest doesn't mistake the
-            # strategy parameters for fixtures
-            runner.__signature__ = inspect.Signature()
-            del runner.__wrapped__
-            return runner
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.booleans = _booleans
-    _st.sampled_from = _sampled_from
-    _st.lists = _lists
-    _st.tuples = _tuples
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_shim__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
+# Bounded CI profile: per-test @settings(max_examples=...) caps are
+# tuned for thoroughness; the CI fast lane trades examples for wall
+# time so the whole lane stays inside its ~5 min budget. deadline is
+# off in both profiles — first-call jit compilation blows any
+# per-example deadline.
+hypothesis.settings.register_profile(
+    "ci", max_examples=10, deadline=None, derandomize=True)
+hypothesis.settings.register_profile(
+    "dev", max_examples=40, deadline=None)
+hypothesis.settings.load_profile("ci" if os.environ.get("CI") else "dev")

@@ -13,6 +13,7 @@ import pytest
 # ----------------------------------------------------------------------
 # roofline: HLO collective parsing
 # ----------------------------------------------------------------------
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo import collective_bytes, count_ops
 
 _FAKE_HLO = """
@@ -69,7 +70,7 @@ ENTRY %m {
 
 def test_collective_bytes_real_lowering():
     """Parse a genuinely compiled module with a known all-reduce."""
-    mesh = jax.make_mesh((1,), ("m",))
+    mesh = make_mesh((1,), ("m",))
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     f = jax.jit(lambda a: a.sum(), in_shardings=(
         jax.sharding.NamedSharding(mesh,
@@ -264,7 +265,7 @@ def test_global_norm_clip():
 def test_sanitize_partition_specs():
     from jax.sharding import PartitionSpec as P
     from repro.launch.dryrun_lib import _sanitize
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     class FakeMesh:
         shape = {"model": 16, "data": 4}

@@ -199,17 +199,27 @@ def test_small_leaf_oracle_fallback():
 
 
 def test_resolve_impl_auto_selection():
-    """`auto` resolves by backend (xla off-TPU), explicit choices pass
-    through, and unknown names fail loudly on every new entry point."""
-    expect = "pallas" if jax.default_backend() == "tpu" else "xla"
-    assert wavg_ops.resolve_impl("auto") == expect
-    assert wavg_ops.resolve_impl(None) == expect
+    """`auto` is decided where the program is lowered: compiled for
+    the CPU it is the XLA form, bit for bit and with no kernel call in
+    the executable. Explicit choices pass through, and unknown names
+    fail loudly on every new entry point."""
+    assert wavg_ops.resolve_impl("auto") == "auto"
+    assert wavg_ops.resolve_impl(None) == "auto"
     assert wavg_ops.resolve_impl("pallas") == "pallas"
     assert wavg_ops.resolve_impl("xla") == "xla"
     with pytest.raises(ValueError, match="impl"):
         wavg_ops.resolve_impl("cuda")
-    G = jnp.ones((2, 256))
-    T, R, valid = _share_meta(2)
+    G = jax.random.normal(jax.random.PRNGKey(0), (4, 3 * 8192))
+    T, R, valid = _share_meta(4)
+
+    def run(impl):
+        return jax.jit(lambda *a: wavg_ops.fused_wavg(*a, impl=impl))
+    if jax.default_backend() == "cpu":
+        hlo = run("auto").lower(G, T, R, valid).compile().as_text()
+        assert "tpu_custom_call" not in hlo
+        for a, b in zip(run("auto")(G, T, R, valid),
+                        run("xla")(G, T, R, valid)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     with pytest.raises(ValueError, match="impl"):
         wavg_ops.fused_wavg(G, T, R, valid, impl="nope")
 
@@ -388,3 +398,22 @@ def test_model_level_kernel_equivalence():
         l2 = model.loss(cfg.with_(**{flag: "pallas_interpret"}),
                         params, batch)
         np.testing.assert_allclose(float(l1), float(l2), rtol=1e-4)
+
+
+def test_ssd_chunked_gradients_finite_at_published_chunk():
+    """mamba2's published 256-step chunk drives exp(cs_i − cs_j) past
+    the fp32 range above the diagonal; the masked entries must not
+    leak 0 · inf = NaN into the gradients."""
+    from repro.models.ssd import ssd_chunked
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    b, s, h, p, n, chunk = 1, 512, 4, 8, 16, 256
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
+    A = -jnp.linspace(1.0, 16.0, h)          # mamba2's A_log init
+    B = jax.random.normal(ks[3], (b, s, 1, n))
+    C = jax.random.normal(ks[4], (b, s, 1, n))
+    grads = jax.grad(
+        lambda *a: jnp.sum(ssd_chunked(*a, chunk)[0]),
+        argnums=(0, 1, 2, 3, 4))(x, dt, A, B, C)
+    for g in grads:
+        assert bool(jnp.all(jnp.isfinite(g)))

@@ -9,10 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.common.sharding import axis_rules, set_mesh
+from repro.common.sharding import axis_rules
 from repro.configs import get_arch_config
 from repro.configs.base import ShapeConfig
-from repro.launch.mesh import train_rules
+from repro.launch.mesh import make_mesh, train_rules
 from repro.models import get_model, make_batch
 from repro.models.moe import _dispatch_indices
 
@@ -36,8 +36,8 @@ def test_expert_parallel_equals_dense(arch):
     g_dense = jax.grad(lambda p: model.loss(
         cfg.with_(moe_dispatch="dense"), p, batch))(params)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh), axis_rules(train_rules(mesh)):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh), axis_rules(train_rules(mesh)):
         l_ep = jax.jit(lambda p, b: model.loss(cfg, p, b))(params, batch)
         g_ep = jax.jit(jax.grad(
             lambda p: model.loss(cfg, p, batch)))(params)
@@ -54,8 +54,8 @@ def test_expert_parallel_under_vmap():
     key = jax.random.PRNGKey(0)
     params = model.init(cfg, key)
     batch = make_batch(cfg, ShapeConfig("t", 64, 2, "train"), key)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh), axis_rules(train_rules(mesh)):
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh), axis_rules(train_rules(mesh)):
         vg = jax.jit(jax.vmap(jax.value_and_grad(
             lambda p, b: model.loss(cfg, p, b))))
         pp = jax.tree.map(lambda x: jnp.stack([x, x]), params)

@@ -1,0 +1,110 @@
+"""Compile every Pallas entry point for a described TPU v5e.
+
+Interpret mode runs a kernel's logic on the CPU but never shows it to
+the TPU compiler, which refuses what the interpreter accepts: block
+shapes off the (8, 128) tiling, casts Mosaic lacks, too much VMEM.
+These tests hand each kernel to that compiler at the widths the main
+path uses (mamba2-780m's published widths for the model kernels) and
+check that the executable holds the kernel (``tpu_custom_call``).
+Nothing runs: a described chip has no memory to run on.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every pytest worker
+imports every test file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.ddal_wavg.kernel import (fused_wavg_flat,
+                                            fused_wavg_q_flat, wavg_flat)
+from repro.kernels.flash_attention.kernel import flash_attention_bhsd
+from repro.kernels.grad_sketch.kernel import sketch_flat
+from repro.kernels.ssd_scan.kernel import ssd_intra_chunk_bchl
+
+M, N = 4, 1 << 22            # eq. 4 pieces × flat plane elements
+Q_BLOCK = 1024               # int8 scale block
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                          # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one (a warning, which the
+    suite treats as an error)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+META = [((M,), jnp.float32), ((M,), jnp.float32), ((M,), jnp.bool_)]
+
+
+def test_wavg_flat(one_chip):
+    _compile(one_chip, lambda G, w: wavg_flat(G, w, interpret=False),
+             ((M, N), jnp.float32), ((M,), jnp.float32))
+
+
+def test_fused_wavg_flat(one_chip):
+    _compile(one_chip,
+             lambda G, *m: fused_wavg_flat(G, *m, interpret=False),
+             ((M, N), jnp.float32), *META)
+
+
+def test_fused_wavg_q_flat(one_chip):
+    _compile(one_chip,
+             lambda Q, S, *m: fused_wavg_q_flat(Q, S, *m, Q_BLOCK,
+                                                interpret=False),
+             ((M, N), jnp.int8), ((M, N // Q_BLOCK), jnp.float32),
+             *META)
+
+
+def test_sketch_flat(one_chip):
+    _compile(one_chip,
+             lambda G, seed: sketch_flat(G, seed, 256, interpret=False),
+             ((2, N), jnp.float32), ((), jnp.int32))
+
+
+def test_flash_attention_bhsd(one_chip):
+    qkv = ((1, 32, 4096, 128), jnp.bfloat16)
+    _compile(one_chip,
+             lambda q, k, v: flash_attention_bhsd(q, k, v,
+                                                  interpret=False),
+             qkv, qkv, qkv)
+
+
+def test_ssd_intra_chunk_bchl(one_chip):
+    # mamba2-780m: 48 heads of 64, d_state 128, chunk 256
+    bn, h, l, p, n = 4, 48, 256, 64, 128
+    _compile(one_chip,
+             lambda *a: ssd_intra_chunk_bchl(*a, interpret=False),
+             ((bn, h, l, p), jnp.float32), ((bn, h, l), jnp.float32),
+             ((bn, h, l), jnp.float32), ((bn, h, l, n), jnp.float32),
+             ((bn, h, l, n), jnp.float32))
