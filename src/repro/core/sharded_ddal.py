@@ -1,6 +1,6 @@
 """DDAL at pod scale — group-agent training of the model zoo.
 
-Mapping (DESIGN.md §3): one GARL agent per **pod**. Parameters,
+Mapping: one GARL agent per **pod**. Parameters,
 optimiser state and knowledge accumulators carry a leading
 ``(n_agents,)`` axis sharded ``P("pod")``; each agent consumes its own
 data stream (its own "environment"). Cross-agent knowledge exchange is
@@ -69,6 +69,15 @@ O(A²·d) instead of the exact O(A²·|params|) Gram. Under the pod
 dispatch this is also what crosses the mesh for relevance — the (A, d)
 sketch rows (O(pods·A·d) bytes), never anything parameter-sized
 (``repro.core.pod_dispatch.relevance_exchange_bytes`` accounts it).
+
+Device scopes: the train step names each of its pieces with
+``jax.named_scope`` — ``ddal.grad`` (the agents' forward and
+backward), ``ddal.window`` (accumulating and resetting the window),
+``ddal.sketch`` (the window sketch), ``ddal.exchange`` (the share
+step's cond), ``ddal.combine`` (relevance and eq. 4 inside it) and
+``ddal.optimizer``. The names reach the compiled ops' ``op_name``
+metadata, through vmap, autodiff and cond, so a profiler trace of
+the step can be split by piece; they change nothing else.
 """
 from __future__ import annotations
 
@@ -434,8 +443,9 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
         # selects instead, and only the eq. 4 combine — the one step
         # that moves data between agents — runs under a cond.
         step = state.step
-        losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(
-            state.params, batch)
+        with jax.named_scope("ddal.grad"):
+            losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(
+                state.params, batch)
         know = state.know
         alive = know.alive if elastic else None
         if elastic and alive is None:
@@ -459,16 +469,18 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
 
         def row_gate(x):
             return jnp.reshape(acc, (-1,) + (1,) * (x.ndim - 1))
-        tg = tree_map(
-            lambda a, g: jnp.where(
-                row_gate(a),
-                a + (T_t * g.astype(jnp.float32)).astype(kdt), a),
-            know.tg, grads)
-        rg = tree_map(
-            lambda a, g: jnp.where(row_gate(a), a + g.astype(kdt), a),
-            know.rg, grads)
-        tsum = know.tsum + jnp.where(acc, T_t, 0.0)
-        rsum = know.rsum + jnp.where(acc, 1.0, 0.0)
+        with jax.named_scope("ddal.window"):
+            tg = tree_map(
+                lambda a, g: jnp.where(
+                    row_gate(a),
+                    a + (T_t * g.astype(jnp.float32)).astype(kdt), a),
+                know.tg, grads)
+            rg = tree_map(
+                lambda a, g: jnp.where(row_gate(a), a + g.astype(kdt),
+                                       a),
+                know.rg, grads)
+            tsum = know.tsum + jnp.where(acc, T_t, 0.0)
+            rsum = know.rsum + jnp.where(acc, 1.0, 0.0)
         sk = know.sk
         if sketch_dim > 0:
             # carry the window sketch: one streaming projection of
@@ -483,8 +495,9 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
                     contrib = jnp.where(alive[:, None], contrib, 0.0)
                 return know.sk + contrib
 
-            sk = jax.lax.cond(warmup, lambda _: know.sk, add_sketch,
-                              None)
+            with jax.named_scope("ddal.sketch"):
+                sk = jax.lax.cond(warmup, lambda _: know.sk, add_sketch,
+                                  None)
         k2 = Knowledge(tg=tg, tsum=tsum, rg=rg, rsum=rsum,
                        rel=know.rel, sk=sk, alive=know.alive)
 
@@ -494,9 +507,10 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
             # the carried (A, d) sketch, so only sketch rows — never
             # parameter planes — cross the mesh for relevance), then
             # the combiner strategy runs eq. 4.
-            rel = exchange.observe(k2.rel, grads=k2.rg, sketch=k2.sk,
-                                   rnd=rnd, alive=alive)
-            return exchange.combine(k2, rel, step, alive=alive), rel
+            with jax.named_scope("ddal.combine"):
+                rel = exchange.observe(k2.rel, grads=k2.rg, sketch=k2.sk,
+                                       rnd=rnd, alive=alive)
+                return exchange.combine(k2, rel, step, alive=alive), rel
 
         gbar_shape = jax.eval_shape(do_share, None)[0]
 
@@ -504,24 +518,28 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
             return (tree_map(lambda x: jnp.zeros(x.shape, x.dtype),
                              gbar_shape), k2.rel)
 
-        gbar, rel = jax.lax.cond(is_share, do_share, no_share, None)
+        with jax.named_scope("ddal.exchange"):
+            gbar, rel = jax.lax.cond(is_share, do_share, no_share, None)
 
         # warm-up steps apply the local gradient, share steps ḡ, and
         # the steps in between only accumulate
-        g_apply = tree_map(lambda g, gb: jnp.where(is_share, gb, g),
-                           grads, gbar)
-        p2, o2 = vopt(g_apply, state.opt_state, state.params, step)
-        update = warmup | is_share
-        if elastic:
-            update = update & alive
-        params = _select_rows(update, p2, state.params)
-        opt_state = _select_rows(update, o2, state.opt_state)
+        with jax.named_scope("ddal.optimizer"):
+            g_apply = tree_map(lambda g, gb: jnp.where(is_share, gb, g),
+                               grads, gbar)
+            p2, o2 = vopt(g_apply, state.opt_state, state.params, step)
+            update = warmup | is_share
+            if elastic:
+                update = update & alive
+            params = _select_rows(update, p2, state.params)
+            opt_state = _select_rows(update, o2, state.opt_state)
+
         def reset(x):                  # a share step empties the window
             return jnp.where(is_share, jnp.zeros_like(x), x)
-        know = k2._replace(
-            tg=tree_map(reset, k2.tg), tsum=reset(k2.tsum),
-            rg=tree_map(reset, k2.rg), rsum=reset(k2.rsum), rel=rel,
-            sk=None if sk is None else reset(sk))
+        with jax.named_scope("ddal.window"):
+            know = k2._replace(
+                tg=tree_map(reset, k2.tg), tsum=reset(k2.tsum),
+                rg=tree_map(reset, k2.rg), rsum=reset(k2.rsum), rel=rel,
+                sk=None if sk is None else reset(sk))
         metrics = {"loss": losses, "step": step,
                    "shared": is_share.astype(jnp.int32)}
         new_state = TrainState(params=params, opt_state=opt_state,
