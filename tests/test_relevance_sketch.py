@@ -16,7 +16,8 @@ from repro.configs.base import GroupSpec
 from repro.core import DDAL, relevance as REL
 from repro.kernels.grad_sketch import ops as SK
 from repro.kernels.grad_sketch import ref as SKref
-from repro.kernels.grad_sketch.kernel import sign_block, sketch_flat
+from repro.kernels.grad_sketch.kernel import (hoisted_sign_block,
+                                             sign_block, sketch_flat)
 
 
 def _tree(n, seed=0, sizes=(37, 3200, 5000)):
@@ -28,15 +29,18 @@ def _tree(n, seed=0, sizes=(37, 3200, 5000)):
 # ----------------------------------------------------------------------
 # kernel vs oracle
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("n,p,d", [(8, 1024, 128), (3, 4097, 256),
-                                   (8, 1000, 128), (16, 2048, 384)])
-def test_sketch_kernel_matches_ref(n, p, d):
+@pytest.mark.parametrize("n,p,d,offset", [
+    (8, 1024, 128, 11), (3, 4097, 256, 11), (8, 1000, 128, 11),
+    (16, 2048, 384, 11),
+    # late leaves: large offsets, ragged last tile
+    (2, 5000, 256, 2_000_000_000), (4, 3000, 128, 123_456_789)])
+def test_sketch_kernel_matches_ref(n, p, d, offset):
     """Pallas kernel (interpret) ≡ one-shot jnp projection: same sign
     stream, only tile-accumulation order differs."""
     G = jnp.asarray(np.random.default_rng(n * p).normal(size=(n, p)),
                     jnp.float32)
-    got = sketch_flat(G, jnp.int32(7), d, offset=11, interpret=True)
-    want = SKref.sketch_flat(G, jnp.int32(7), d, offset=11)
+    got = sketch_flat(G, jnp.int32(7), d, offset=offset, interpret=True)
+    want = SKref.sketch_flat(G, jnp.int32(7), d, offset=offset)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
 
@@ -51,6 +55,20 @@ def test_sketch_xla_path_matches_ref():
     want = SKref.sketch_flat(G, jnp.int32(3), 192, offset=5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("start", [0, 123_456_789, np.uint32(2**31 + 5)])
+@pytest.mark.parametrize("seed", [0, 7, 0x7FFFFFFF, -1])  # -1: 0xFFFFFFFF
+def test_hoisted_sign_block_is_sign_block(seed, start, d):
+    """The kernel's generator (shape-only base + one scalar, shortened
+    mix, sign bit placed into 1.0) gives ``sign_block``'s signs bit for
+    bit, also where seed and position products wrap in uint32."""
+    want = np.asarray(sign_block(np.int32(seed), start, 1024, d))
+    got = np.asarray(hoisted_sign_block(np.int32(seed), start, 1024, d))
+    assert got.dtype == want.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
 
 
 def test_sign_block_positional_and_balanced():

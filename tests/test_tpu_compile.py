@@ -86,10 +86,12 @@ def test_fused_wavg_q_flat(one_chip):
              *META)
 
 
-def test_sketch_flat(one_chip):
+# mamba2-780m's embedding leaf: 50,288 × 1,536 positions per agent
+@pytest.mark.parametrize("p", [N, 50_288 * 1_536])
+def test_sketch_flat(one_chip, p):
     _compile(one_chip,
              lambda G, seed: sketch_flat(G, seed, 256, interpret=False),
-             ((2, N), jnp.float32), ((), jnp.int32))
+             ((2, p), jnp.float32), ((), jnp.int32))
 
 
 def test_flash_attention_bhsd(one_chip):
