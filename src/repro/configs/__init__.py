@@ -27,6 +27,7 @@ _ARCH_MODULES = {
     "zamba2-7b": "zamba2_7b",
     "mamba2-780m": "mamba2_780m",
     "granite-3-8b": "granite_3_8b",
+    "kanana-2-30b-a3b": "kanana_2_30b_a3b",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)
@@ -42,7 +43,7 @@ def get_arch_config(arch_id: str) -> ArchConfig:
 def arch_for_shape(cfg: ArchConfig, shape_name: str) -> ArchConfig:
     """Apply per-shape variants: dense/VLM/audio archs get the
     sliding-window attention variant for long_500k (sub-quadratic
-    requirement — DESIGN.md §5); SSM/hybrid run natively."""
+    requirement); SSM/hybrid run natively."""
     if shape_name == "long_500k" and cfg.family not in ("ssm", "hybrid"):
         if cfg.sliding_window is None:
             return cfg.with_(sliding_window=LONG_CONTEXT_WINDOW)

@@ -23,6 +23,32 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_zloss: float = 1e-3
     aux_loss: float = 1e-2     # load-balance auxiliary loss weight
+    # -- routing -------------------------------------------------------
+    scoring: str = "softmax"   # softmax | sigmoid (DeepSeek-V3)
+    router_bias: bool = False  # noaux_tc: a correction bias added to
+                               # the scores for selection only
+    routed_scaling: float = 1.0    # gates times this after top-k
+    norm_topk: bool = True     # gates of the chosen k sum to 1
+    # -- held experts (expert parallelism, one device's share) ---------
+    n_held: int = 0            # experts this layer holds; 0 = all of
+                               # them. Routing still chooses over all
+                               # ``n_experts``; absent experts add
+                               # nothing, and dispatch drops no token
+    first_held: int = 0        # id of the first held expert
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown MoE scoring {self.scoring!r}")
+        if self.scoring == "sigmoid" and self.aux_loss:
+            raise ValueError("sigmoid routing balances by its correction "
+                             "bias (noaux_tc): set aux_loss to 0")
+        if self.n_held and not (
+                0 <= self.first_held
+                and self.first_held + self.n_held <= self.n_experts):
+            last = self.first_held + self.n_held - 1
+            raise ValueError(
+                f"held experts {self.first_held}..{last} lie outside "
+                f"the {self.n_experts} experts")
 
 
 @dataclass(frozen=True)
@@ -33,6 +59,8 @@ class MLAConfig:
     qk_rope_dim: int = 64
     v_dim: int = 128
     q_lora_rank: Optional[int] = None   # V2-Lite: queries not compressed
+    rope_interleave: bool = False       # V3: rotate channel pairs
+                                        # (2i, 2i+1), not halves
 
 
 @dataclass(frozen=True)
@@ -158,7 +186,8 @@ class ArchConfig:
             kw["moe"] = replace(self.moe, n_experts=4,
                                 top_k=min(self.moe.top_k, 2),
                                 expert_ff=128,
-                                n_shared=min(self.moe.n_shared, 1))
+                                n_shared=min(self.moe.n_shared, 1),
+                                n_held=0, first_held=0)
         if self.mla is not None:
             kw["mla"] = replace(self.mla, kv_lora_rank=64, qk_nope_dim=32,
                                 qk_rope_dim=16, v_dim=32)
@@ -200,7 +229,7 @@ INPUT_SHAPES = {
 }
 
 # Dense (full-attention) archs fall back to a sliding-window variant for
-# long_500k (sub-quadratic requirement) — see DESIGN.md §5.
+# long_500k (sub-quadratic requirement).
 LONG_CONTEXT_WINDOW = 8_192
 
 

@@ -2,7 +2,7 @@
 [arXiv:2405.04434]. MLA kv_lora=512; 2 shared + 64 routed experts,
 top-6 (the assignment's per-arch note says "160 routed" which is
 DeepSeek-V2-*full*; the config line's 64e matches V2-Lite and the cited
-paper, so we use 64 — recorded in DESIGN.md §5). Layer 0 is dense with
+paper, so we use 64). Layer 0 is dense with
 d_ff 10944 per the model card."""
 from repro.configs.base import ArchConfig, MLAConfig, MoEConfig
 

@@ -88,9 +88,10 @@ class ExchangeProtocol:
                  schedule: Optional[TopologySchedule],
                  estimator: RelevanceEstimator,
                  delay_model: DelayModel, combiner,
-                 static_topology: Topology, transport=None):
+                 static_topology: Topology, transport=None, mesh=None):
         self.spec = spec
         self.kind = kind
+        self.mesh = mesh        # the mesh the combiner was built over
         self.schedule = schedule
         self.estimator = estimator
         self.delay_model = delay_model
@@ -450,4 +451,4 @@ def build_exchange(spec, mesh=None, *, kind: Optional[str] = None,
                             estimator=estimator,
                             delay_model=delay_model, combiner=combiner,
                             static_topology=static_topo,
-                            transport=transport)
+                            transport=transport, mesh=mesh)

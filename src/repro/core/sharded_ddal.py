@@ -70,6 +70,16 @@ dispatch this is also what crosses the mesh for relevance — the (A, d)
 sketch rows (O(pods·A·d) bytes), never anything parameter-sized
 (``repro.core.pod_dispatch.relevance_exchange_bytes`` accounts it).
 
+One agent per device: with a ``mesh`` whose agent axes
+(``repro.launch.shardings.ddal_agent_axis``) hold one device per
+agent, or a few agents each, the per-agent pieces — the forward and
+backward pass and the window sketch — run under ``shard_map`` on the
+agents' own devices, each device seeing only its agents. Nothing of
+one agent's pass then crosses a device, and a kernel inside (the
+sketch's Pallas call, the expert layer's grouped product) sees one
+device's arrays. A device that holds one agent runs it unbatched.
+Without a mesh every agent runs under ``vmap`` on one device.
+
 Device scopes: the train step names each of its pieces with
 ``jax.named_scope`` — ``ddal.grad`` (the agents' forward and
 backward), ``ddal.window`` (accumulating and resetting the window),
@@ -380,6 +390,55 @@ def revive_agents(state: TrainState, mask,
                           know=know)
 
 
+def _per_agent_map(mesh, spec: GroupSpec):
+    """``per_agent(fn, shared=(), stacked=False)`` maps ``fn`` over
+    the leading agent axis of its arguments (``shared`` names the
+    positions of arguments every agent gets whole) and stacks its
+    outputs: under ``shard_map`` on the agents' own devices when
+    ``mesh`` spreads the agents over its agent axes, else under
+    ``vmap``. Where a device of the mesh holds one agent, ``fn`` runs
+    on it unbatched: the TPU compiler refuses a ragged dot with a batch
+    dimension, which ``vmap`` gives the held experts' grouped products
+    even over one agent. A ``stacked`` ``fn`` takes and returns the
+    agent axis itself, and gets each device's agents at once."""
+    from repro.launch.shardings import ddal_agent_axis
+    axis = ddal_agent_axis(mesh, spec.pod_axis) if mesh is not None else None
+    names = (axis,) if isinstance(axis, str) else tuple(axis or ())
+    devices = 1
+    for a in names:
+        devices *= mesh.shape[a]
+    if names and spec.n_agents % devices:
+        raise ValueError(
+            f"{spec.n_agents} agents do not spread evenly over the "
+            f"{devices} devices of mesh axes {names}")
+    local = spec.n_agents // devices if names else spec.n_agents
+
+    def per_agent(fn, shared=(), stacked=False):
+        def mapped(*args):
+            if stacked:
+                return fn(*args)
+            if names and local == 1:
+                one = [a if i in shared else tree_map(lambda x: x[0], a)
+                       for i, a in enumerate(args)]
+                return tree_map(lambda x: x[None], fn(*one))
+            return jax.vmap(fn, in_axes=tuple(
+                None if i in shared else 0 for i in range(len(args))))(
+                    *args)
+        if not names:
+            return mapped
+        from jax.sharding import PartitionSpec as P
+
+        def run(*args):
+            specs = tuple(P() if i in shared else P(axis)
+                          for i in range(len(args)))
+            return jax.shard_map(
+                mapped, mesh=mesh, in_specs=specs, out_specs=P(axis),
+                axis_names=set(names), check_vma=False)(*args)
+        return run
+
+    return per_agent
+
+
 def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
                           opt: Optimizer,
                           relevance: Optional[jnp.ndarray] = None,
@@ -405,12 +464,24 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
     to run the real collective path; without a mesh the mathematically
     identical single-device decomposition runs instead, so the flag is
     meaningful on a 1-CPU rig too.
+
+    ``mesh`` also places the per-agent pieces (module docstring: one
+    agent per device); a prebuilt ``exchange`` must have been built
+    over the same mesh. The step's metrics carry ``loss`` and,
+    where the model counts them (``Model.loss_stats``), its counters,
+    each with a leading (n_agents,) axis.
     """
     if loss_fn is None:
         model = get_model(cfg)
-
-        def loss_fn(params, batch):        # noqa: F811
-            return model.loss(cfg, params, batch)
+        if model.loss_stats is not None:
+            def loss_stats(params, batch):
+                return model.loss_stats(cfg, params, batch)
+        else:
+            def loss_stats(params, batch):
+                return model.loss(cfg, params, batch), {}
+    else:
+        def loss_stats(params, batch):
+            return loss_fn(params, batch), {}
     if exchange is None:
         from repro.core.exchange import build_exchange
         exchange = build_exchange(spec, mesh, kind="streaming",
@@ -420,13 +491,17 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
         raise ValueError(
             f"the streaming train step needs a 'streaming' exchange "
             f"protocol, got {exchange.kind!r}")
-    elif (topology is not None or relevance is not None
-          or mesh is not None):
+    elif topology is not None or relevance is not None:
         raise ValueError(
-            "topology/relevance/mesh would be silently ignored: they "
-            "are baked into the protocol at build time — pass them to "
+            "topology/relevance would be silently ignored: they are "
+            "baked into the protocol at build time — pass them to "
             "build_exchange(...) instead when supplying a prebuilt "
             "exchange")
+    elif mesh is not None and mesh is not exchange.mesh:
+        raise ValueError(
+            "the prebuilt exchange was built over another mesh than the "
+            "step's: pass the step's mesh to build_exchange(...)")
+    per_agent = _per_agent_map(mesh, spec)
     learn_rel = exchange.learns
     sketch_dim = exchange.sketch_dim
     # elastic membership is a *static* build fact: non-elastic specs
@@ -444,8 +519,9 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
         # that moves data between agents — runs under a cond.
         step = state.step
         with jax.named_scope("ddal.grad"):
-            losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(
-                state.params, batch)
+            (losses, stats), grads = per_agent(
+                jax.value_and_grad(loss_stats, has_aux=True))(
+                    state.params, batch)
         know = state.know
         alive = know.alive if elastic else None
         if elastic and alive is None:
@@ -490,7 +566,8 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
             # ((step + mb − 1) // mb), so at share time sk IS the
             # sketch of rg — nothing parameter-sized is re-read.
             def add_sketch(_):
-                contrib = exchange.sketch_step(grads, rnd)
+                contrib = per_agent(exchange.sketch_step, shared=(1,),
+                                    stacked=True)(grads, rnd)
                 if elastic:
                     contrib = jnp.where(alive[:, None], contrib, 0.0)
                 return know.sk + contrib
@@ -541,7 +618,7 @@ def make_group_train_step(cfg: ArchConfig, spec: GroupSpec,
                 rg=tree_map(reset, k2.rg), rsum=reset(k2.rsum), rel=rel,
                 sk=None if sk is None else reset(sk))
         metrics = {"loss": losses, "step": step,
-                   "shared": is_share.astype(jnp.int32)}
+                   "shared": is_share.astype(jnp.int32), **stats}
         new_state = TrainState(params=params, opt_state=opt_state,
                                know=know, step=step + 1)
         return new_state, metrics

@@ -9,26 +9,27 @@ import jax
 from jax.sharding import AxisType
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
     """``jax.make_mesh`` with every axis ``Auto``: the model code
     places arrays with ``with_sharding_constraint`` and lets GSPMD
     propagate the rest, which ``Explicit`` axes (jax's default) turn
-    into hard asserts."""
+    into hard asserts. ``devices`` defaults to every device."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(axes))
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16×16 = 256 chips over ("data", "model").
     Multi-pod: 2×16×16 = 512 chips over ("pod", "data", "model") —
-    one GARL agent per pod (DESIGN.md §3)."""
+    one GARL agent per pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
 
 
 def make_pod_mesh(n_pods: int, devices_per_pod: int = None,
-                  pod_axis: str = "pod"):
+                  pod_axis: str = "pod", devices=None):
     """Two-level ``(pod_axis, "agent")`` mesh for hierarchical DDAL
     dispatch: the ``"agent"`` axis is the fast intra-pod interconnect
     (ICI on a TPU pod), ``pod_axis`` the slow cross-pod one (DCN).
@@ -37,15 +38,18 @@ def make_pod_mesh(n_pods: int, devices_per_pod: int = None,
 
     On a single-host simulation rig the devices come from
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — the same
-    mesh the multi-device test lane uses."""
-    n_dev = jax.device_count()
+    mesh the multi-device test lane uses. ``devices`` (default: every
+    device) are laid out pod-major: with one agent per device, agent
+    i of the DDAL group runs on ``devices[i]``."""
+    n_dev = len(devices) if devices is not None else jax.device_count()
     if devices_per_pod is None:
         if n_pods < 1 or n_dev % n_pods:
             raise ValueError(
                 f"{n_dev} devices do not split into {n_pods} pods — "
                 f"pass devices_per_pod explicitly")
         devices_per_pod = n_dev // n_pods
-    return make_mesh((n_pods, devices_per_pod), (pod_axis, "agent"))
+    return make_mesh((n_pods, devices_per_pod), (pod_axis, "agent"),
+                     devices=devices)
 
 
 def train_rules(mesh, pod_axis: str = "pod") -> dict:

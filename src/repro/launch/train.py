@@ -276,7 +276,8 @@ def main(argv=None):
         # the state is donated: a step never holds two copies of the
         # params, optimiser moments and exchange window at once
         step_fn = jax.jit(make_group_train_step(cfg, spec, opt,
-                                                exchange=exchange),
+                                                exchange=exchange,
+                                                mesh=mesh),
                           donate_argnums=0)
         n_params = sum(int(x.size) for x in
                        jax.tree.leaves(state.params)) // args.agents

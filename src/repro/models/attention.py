@@ -216,11 +216,13 @@ def mla_attention(cfg, p, x, positions, layer_cache=None):
 
     q = (x @ p["wq"].astype(cdt)).reshape(B, S, H, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = rope_lib.rope(q_rope, positions, cfg.rope_theta)
-
     dkv = x @ p["w_dkv"].astype(cdt)
     ckv = rms_norm(dkv[..., :m.kv_lora_rank], p["ln_ckv"], cfg.norm_eps)
     k_rope = dkv[..., m.kv_lora_rank:][:, :, None, :]       # 1 shared head
+    if m.rope_interleave:
+        q_rope = rope_lib.deinterleave(q_rope)
+        k_rope = rope_lib.deinterleave(k_rope)
+    q_rope = rope_lib.rope(q_rope, positions, cfg.rope_theta)
     k_rope = rope_lib.rope(k_rope, positions, cfg.rope_theta)[:, :, 0, :]
 
     new_cache = layer_cache

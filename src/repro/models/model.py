@@ -14,7 +14,7 @@ device allocation) — the multi-pod dry-run lowers against these.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,11 +32,14 @@ class Model:
     forward: Callable            # full-seq: (cfg, params, batch, cache)
     decode: Callable             # (cfg, params, batch, cache)
     make_cache: Callable         # (cfg, batch_size, max_len)
+    # (cfg, params, batch) -> (loss, stats): counters of the pass the
+    # train step reports beside the loss; None = no counters
+    loss_stats: Optional[Callable] = None
 
 
 def _tf_prefill(cfg, params, batch, cache):
-    logits, _, new_cache = tf.transformer_forward(cfg, params, batch,
-                                                  cache=cache)
+    logits, _, new_cache, _ = tf.transformer_forward(cfg, params, batch,
+                                                     cache=cache)
     return logits, new_cache
 
 
@@ -57,6 +60,7 @@ _FAMILIES: Dict[str, Model] = {
         forward=_tf_prefill,
         decode=tf.transformer_decode,
         make_cache=tf.make_transformer_cache,
+        loss_stats=tf.transformer_loss_stats,
     ),
     "ssm": Model(
         init=ssm.init_ssm_model,

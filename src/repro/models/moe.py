@@ -8,11 +8,24 @@ gather into the MoE all-to-all pattern.
 
 Top-k routing with normalised gates (Qwen3 / DeepSeek style), capacity
 factor with token dropping, load-balance auxiliary loss and router
-z-loss.
+z-loss. ``MoEConfig.scoring="sigmoid"`` routes as DeepSeek-V3's
+noaux_tc: the top k of the sigmoid scores plus a correction bias
+(``router_bias``) are chosen, and the gates are the chosen scores,
+normalised to sum 1 (``norm_topk``), times ``routed_scaling``.
+
+Held experts (``MoEConfig.n_held > 0``): the layer holds one device's
+share of the experts, ``first_held`` to ``first_held + n_held - 1``.
+The router still scores all ``n_experts`` and the top k are chosen
+over all of them; the layer computes only the pairs routed to its
+held experts, and the others add nothing here (their device adds
+them). Dispatch drops no token: the pairs are sorted by held expert
+and run through a grouped product (``jax.lax.ragged_dot``) sized for
+the worst case, every token on every held expert it chose. The layer
+counts the token-expert pairs each held expert got.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,14 +41,18 @@ def init_moe(cfg, key):
     E, F, Ne = cfg.d_model, moe.expert_ff, moe.n_experts
     dt = cfg.dtype("param")
     kg, ku, kd = jax.random.split(ke, 3)
+    Nh = moe.n_held or Ne
     p = {
         "router": dense_init(kr, (E, Ne), dt),
         "experts": {
-            "w_gate": dense_init(kg, (Ne, E, F), dt),
-            "w_up": dense_init(ku, (Ne, E, F), dt),
-            "w_down": dense_init(kd, (Ne, F, E), dt),
+            "w_gate": dense_init(kg, (Nh, E, F), dt),
+            "w_up": dense_init(ku, (Nh, E, F), dt),
+            "w_down": dense_init(kd, (Nh, F, E), dt),
         },
     }
+    if moe.router_bias:
+        # selection-only correction bias; starts at zero
+        p["router_bias"] = jnp.zeros((Ne,), dt)
     if moe.n_shared:
         # shared (always-on) experts fused into one wider SwiGLU
         p["shared"] = init_swiglu(ks, E, F * moe.n_shared, dt)
@@ -184,46 +201,124 @@ def _expert_axis():
     return axis
 
 
-def moe_apply(cfg, p, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, E) → (out, aux_loss).
+def _route(moe, p, x, cdt):
+    """Router scores, the chosen experts and their gates.
+    Returns (logits, probs, gate, gate_idx); probs, the softmax the
+    load-balance loss reads, is None for sigmoid routing."""
+    if moe.scoring == "sigmoid":
+        # the router in float32, as DeepSeek-V3 computes it: selection
+        # is discontinuous, so it should not hang on bf16 rounding
+        logits = jnp.dot(x.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        choice = scores
+        if moe.router_bias:
+            choice = scores + jax.lax.stop_gradient(
+                p["router_bias"].astype(jnp.float32))
+        _, gate_idx = jax.lax.top_k(choice, moe.top_k)
+        gate = jnp.take_along_axis(scores, gate_idx, axis=-1)
+        if moe.norm_topk:
+            gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        probs = None
+    else:
+        logits = (x @ p["router"].astype(jnp.float32).astype(cdt)
+                  ).astype(jnp.float32)                  # (B,S,Ne)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, gate_idx = jax.lax.top_k(probs, moe.top_k)  # (B,S,k)
+        if moe.norm_topk:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if moe.routed_scaling != 1.0:
+        gate = gate * moe.routed_scaling
+    return logits, probs, gate, gate_idx
 
-    Two dispatch engines with identical drop semantics (tested):
+
+def _moe_held(cfg, p, x, gate, gate_idx):
+    """Dropless dispatch onto the held experts. x: (B, S, E); gate,
+    gate_idx: (B, S, k) over all experts. Returns the (B, S, E) sum of
+    the held experts' gated outputs and the pairs each held expert got
+    ((n_held,) int32)."""
+    moe = cfg.moe
+    B, S, E = x.shape
+    k, Nh = moe.top_k, moe.n_held
+    cdt = cfg.dtype("compute")
+    T = B * S
+    local = gate_idx.reshape(T * k) - moe.first_held
+    held = (local >= 0) & (local < Nh)
+    key = jnp.where(held, local, Nh).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=Nh + 1)[:Nh].astype(jnp.int32)
+    # a token chooses an expert at most once, so the held experts get
+    # at most min(k, n_held) pairs of each token: room for every pair
+    rows = T * min(k, Nh)
+    order = jnp.argsort(key, stable=True)[:rows]         # held first
+    tok = order // k
+    valid = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    w = gate.reshape(T * k)[order]
+    ex = p["experts"]
+    live = (sizes > 0)[:, None, None]
+
+    def grouped(a, name):
+        # the TPU's ragged dot leaves what no group covers unwritten:
+        # the rows outside every group, and an empty group's weight
+        # gradient. Select them away, here and (through the select's
+        # transpose) in the backward pass
+        w_ = jnp.where(live, ex[name].astype(cdt), 0)
+        return jnp.where(valid, jax.lax.ragged_dot(a, w_, sizes), 0)
+
+    xs = jnp.where(valid, x.reshape(T, E)[tok], 0).astype(cdt)
+    with jax.named_scope("ddal.experts"):
+        h = jax.nn.silu(grouped(xs, "w_gate")) * grouped(xs, "w_up")
+        y = grouped(h, "w_down")
+    out = jnp.zeros((T, E), jnp.float32).at[tok].add(
+        y.astype(jnp.float32) * w[:, None])
+    return out.reshape(B, S, E).astype(cdt), sizes
+
+
+def moe_apply(cfg, p, x) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                  Dict[str, jnp.ndarray]]:
+    """x: (B, S, E) → (out, aux_loss, stats).
+
+    Three dispatch engines. Two with identical drop semantics (tested):
       * dense scatter (reference) — single-device/no-mesh path;
       * expert-parallel shard_map (gather dispatch + psum combine) —
         selected automatically under a mesh whose rules shard
-        "experts"; cuts the MoE collective term ~500× (EXPERIMENTS.md
-        §Perf).
+        "experts"; cuts the MoE collective term ~500×.
+    And, where the layer holds a share of the experts
+    (``MoEConfig.n_held``), the dropless held-experts dispatch, whose
+    ``stats["held_pairs"]`` is the (n_held,) count of pairs each held
+    expert got; ``stats`` is empty otherwise.
     """
     moe = cfg.moe
     B, S, E = x.shape
     Ne, k = moe.n_experts, moe.top_k
     cdt = cfg.dtype("compute")
 
-    logits = (x @ p["router"].astype(jnp.float32).astype(cdt)
-              ).astype(jnp.float32)                      # (B,S,Ne)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, gate_idx = jax.lax.top_k(probs, k)             # (B,S,k)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)  # normalised top-k
-
-    e_flat = gate_idx.reshape(B, S * k)                  # (B, S·k)
-    gate_flat = gate.reshape(B, S * k)
-
-    axis = None if cfg.moe_dispatch == "dense" else _expert_axis()
-    if axis is not None and Ne % jax.sharding.get_abstract_mesh(
-            ).shape[axis] == 0:
-        out = _moe_expert_parallel(cfg, p, x, gate_flat, e_flat, axis)
+    logits, probs, gate, gate_idx = _route(moe, p, x, cdt)
+    stats = {}
+    if moe.n_held:
+        out, stats["held_pairs"] = _moe_held(cfg, p, x, gate, gate_idx)
     else:
-        out = _moe_dense(cfg, p, x, gate_flat, e_flat)
+        e_flat = gate_idx.reshape(B, S * k)              # (B, S·k)
+        gate_flat = gate.reshape(B, S * k)
+        axis = None if cfg.moe_dispatch == "dense" else _expert_axis()
+        if axis is not None and Ne % jax.sharding.get_abstract_mesh(
+                ).shape[axis] == 0:
+            out = _moe_expert_parallel(cfg, p, x, gate_flat, e_flat, axis)
+        else:
+            out = _moe_dense(cfg, p, x, gate_flat, e_flat)
 
     if moe.n_shared:
         out = out + swiglu(p["shared"], x, cdt)
 
     # ---- auxiliary losses --------------------------------------------
-    # load balance: Ne * Σ_e (fraction dispatched)·(mean router prob)
-    frac = jnp.mean(jax.nn.one_hot(gate_idx, Ne, dtype=jnp.float32),
-                    axis=(0, 1, 2)) * k
-    pmean = jnp.mean(probs, axis=(0, 1))
-    aux = moe.aux_loss * Ne * jnp.sum(frac * pmean)
-    zloss = moe.router_zloss * jnp.mean(
-        jnp.square(jax.scipy.special.logsumexp(logits, axis=-1)))
-    return out, aux + zloss
+    aux = jnp.float32(0.0)
+    if moe.aux_loss:
+        # load balance: Ne * Σ_e (fraction dispatched)·(mean router prob)
+        frac = jnp.mean(jax.nn.one_hot(gate_idx, Ne, dtype=jnp.float32),
+                        axis=(0, 1, 2)) * k
+        pmean = jnp.mean(probs, axis=(0, 1))
+        aux = aux + moe.aux_loss * Ne * jnp.sum(frac * pmean)
+    if moe.router_zloss:
+        aux = aux + moe.router_zloss * jnp.mean(
+            jnp.square(jax.scipy.special.logsumexp(logits, axis=-1)))
+    return out, aux, stats

@@ -27,6 +27,15 @@ def _apply_rotary(x, cos, sin):
                             x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def deinterleave(x):
+    """Channel pairs (2i, 2i+1) of the last axis moved to (i, i + D/2):
+    rotate-half RoPE on the result is RoPE on interleaved pairs
+    (DeepSeek-V3's ``rope_interleave``), up to the same reordering of
+    every query and key, which leaves their dot products unchanged."""
+    *lead, d = x.shape
+    return jnp.swapaxes(x.reshape(*lead, d // 2, 2), -1, -2).reshape(x.shape)
+
+
 def rope(x, positions, theta: float):
     """Standard RoPE. x: (B, S, H, D); positions: (B, S)."""
     ang = _angles(positions, x.shape[-1], theta)      # (B,S,D/2)

@@ -2,6 +2,11 @@
 audio families. Layers are uniform and scanned (``lax.scan`` over
 stacked per-layer parameters) so HLO size and compile time are flat in
 depth; DeepSeek's leading dense layer runs outside the scan.
+
+Device scopes: latent attention runs under ``jax.named_scope("ddal.mla")``
+and the expert layer under ``"ddal.moe"`` (its held experts' products
+under ``"ddal.experts"``, ``repro.models.moe``), so a profiler trace
+of a train step splits them out of ``ddal.grad``.
 """
 from __future__ import annotations
 
@@ -75,9 +80,10 @@ def _layer_apply(cfg, p, x, positions, cond, layer_cache, *,
     cdt = cfg.dtype("compute")
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.mla is not None:
-        a, new_cache = attn.mla_attention(cfg, p["attn"], h, positions,
-                                          layer_cache and
-                                          layer_cache.get("kv"))
+        with jax.named_scope("ddal.mla"):
+            a, new_cache = attn.mla_attention(cfg, p["attn"], h, positions,
+                                              layer_cache and
+                                              layer_cache.get("kv"))
     else:
         a, new_cache = attn.self_attention(cfg, p["attn"], h, positions,
                                            layer_cache=layer_cache and
@@ -92,11 +98,13 @@ def _layer_apply(cfg, p, x, positions, cond, layer_cache, *,
         x = x + cx
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     aux = jnp.float32(0.0)
+    stats = {}
     if dense_ff:
         f = (gelu_mlp(p["mlp"], h2, cdt) if cfg.family == "audio"
              else swiglu(p["mlp"], h2, cdt))
     else:
-        f, aux = moe_apply(cfg, p["moe"], h2)
+        with jax.named_scope("ddal.moe"):
+            f, aux, stats = moe_apply(cfg, p["moe"], h2)
     x = x + f
     out_cache = None
     if layer_cache is not None:
@@ -105,7 +113,7 @@ def _layer_apply(cfg, p, x, positions, cond, layer_cache, *,
             out_cache["kv"] = new_cache
         if new_xcache is not None:
             out_cache["xkv"] = new_xcache
-    return x, aux, out_cache
+    return x, aux, out_cache, stats
 
 
 def _embed(cfg, params, tokens, positions, vision=None):
@@ -128,7 +136,8 @@ def transformer_forward(cfg, params, batch, cache=None):
     """Full-sequence pass (train / prefill).
 
     batch: tokens, positions [, labels, vision, cond].
-    Returns (logits, aux_loss, new_cache).
+    Returns (logits, aux_loss, new_cache, stats): stats are the scanned
+    layers' counters from ``moe_apply``, each stacked over the layers.
     """
     cdt = cfg.dtype("compute")
     cond = batch.get("cond")
@@ -144,17 +153,18 @@ def transformer_forward(cfg, params, batch, cache=None):
     if cfg.first_k_dense:
         lc = None if cache is None else jax.tree.map(
             lambda c: c[0], cache["layer0"])
-        x, _, l0_cache = _layer_apply(cfg, params["layer0"], x, positions,
-                                      cond, lc, dense_ff=True)
+        x, _, l0_cache, _ = _layer_apply(cfg, params["layer0"], x,
+                                         positions, cond, lc,
+                                         dense_ff=True)
         if l0_cache is not None:
             l0_cache = jax.tree.map(lambda c: c[None], l0_cache)
 
     def body(carry, per_layer):
         xc, aux_sum = carry
         lp, lcache = per_layer
-        xo, aux, new_cache = _layer_apply(cfg, lp, xc, positions, cond,
-                                          lcache, dense_ff=dense_ff)
-        return (xo, aux_sum + aux), new_cache
+        xo, aux, new_cache, stats = _layer_apply(
+            cfg, lp, xc, positions, cond, lcache, dense_ff=dense_ff)
+        return (xo, aux_sum + aux), (new_cache, stats)
 
     body_fn = body
     if cfg.remat and cache is None:
@@ -166,12 +176,12 @@ def transformer_forward(cfg, params, batch, cache=None):
     unroll = cfg.unroll_layers
     if scan_cache is None:
         # scan still needs a per-layer xs structure: params only
-        (x, aux_sum), _ = jax.lax.scan(
+        (x, aux_sum), (_, stats) = jax.lax.scan(
             lambda c, lp: body_fn(c, (lp, None)),
             (x, jnp.float32(0.0)), params["layers"], unroll=unroll)
         new_cache = None
     else:
-        (x, aux_sum), new_layer_caches = jax.lax.scan(
+        (x, aux_sum), (new_layer_caches, stats) = jax.lax.scan(
             body_fn, (x, jnp.float32(0.0)),
             (params["layers"], scan_cache), unroll=unroll)
         new_cache = {"layers": new_layer_caches}
@@ -180,7 +190,7 @@ def transformer_forward(cfg, params, batch, cache=None):
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _lm_head(cfg, params, x)
-    return logits, aux_sum, new_cache
+    return logits, aux_sum, new_cache, stats
 
 
 def _lm_head(cfg, params, x):
@@ -197,19 +207,31 @@ def _lm_head(cfg, params, x):
 def transformer_decode(cfg, params, batch, cache):
     """One-token decode. batch: tokens (B,1) or (B,K,1) for audio,
     positions (B,1) / (B,3,1); cache from make_cache/prefill."""
-    logits, _, new_cache = transformer_forward(cfg, params, batch,
-                                               cache=cache)
+    logits, _, new_cache, _ = transformer_forward(cfg, params, batch,
+                                                  cache=cache)
     return logits, new_cache
 
 
 def transformer_loss(cfg, params, batch):
-    logits, aux, _ = transformer_forward(cfg, params, batch)
-    labels = batch["labels"]
-    if cfg.family == "vlm":
-        # labels cover the full (vision_prefix + text) sequence; the
-        # data pipeline marks vision positions with -100.
-        pass
-    return cross_entropy(logits, labels) + aux
+    return transformer_loss_stats(cfg, params, batch)[0]
+
+
+def transformer_loss_stats(cfg, params, batch):
+    """(loss, stats). Where the expert layers hold a share of the
+    experts, stats counts this pass's token-expert pairs routed to
+    them: ``held_pairs``, the sum over layers and held experts, and
+    ``held_pairs_max``, the most any held expert got in one layer.
+    Empty otherwise."""
+    logits, aux, _, layer_stats = transformer_forward(cfg, params, batch)
+    # labels cover the full (vision_prefix + text) sequence for vlm;
+    # the data pipeline marks vision positions with -100.
+    loss = cross_entropy(logits, batch["labels"]) + aux
+    stats = {}
+    if "held_pairs" in layer_stats:
+        pairs = layer_stats["held_pairs"]                # (L, n_held)
+        stats = {"held_pairs": jnp.sum(pairs),
+                 "held_pairs_max": jnp.max(pairs)}
+    return loss, stats
 
 
 def make_transformer_cache(cfg, batch: int, max_len: int):

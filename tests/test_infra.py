@@ -259,6 +259,18 @@ def test_global_norm_clip():
                                rtol=1e-4)
 
 
+def test_tree_sq_norm_of_a_large_leaf():
+    """An embedding-sized leaf's squared norm to float32 rounding, under
+    vmap over agents as the train step's optimizer takes it (a flat
+    float32 dot read 2% short here)."""
+    from repro.common.pytree import tree_sq_norm
+    x = np.random.default_rng(0).standard_normal((2, 1 << 23)).astype(
+        np.float32)
+    want = (x.astype(np.float64) ** 2).sum(axis=1)
+    got = jax.jit(jax.vmap(lambda v: tree_sq_norm({"w": v})))(x)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)
+
+
 # ----------------------------------------------------------------------
 # sharding helpers
 # ----------------------------------------------------------------------

@@ -110,3 +110,55 @@ def test_ssd_intra_chunk_bchl(one_chip):
              ((bn, h, l, p), jnp.float32), ((bn, h, l), jnp.float32),
              ((bn, h, l), jnp.float32), ((bn, h, l, n), jnp.float32),
              ((bn, h, l, n), jnp.float32))
+
+
+def _held_experts_grad(one_chip, agents=None):
+    """kanana-2-30b-a3b's expert layer, forward and backward, at its
+    published widths with 8 of 128 experts held, lowered for the
+    described chip; under ``vmap`` over ``agents`` agents if given."""
+    import dataclasses
+
+    from repro.configs import get_arch_config
+    from repro.models.moe import moe_apply
+
+    cfg = get_arch_config("kanana-2-30b-a3b")
+    cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, n_held=8))
+    E, F, Ne = cfg.d_model, cfg.moe.expert_ff, cfg.moe.n_experts
+    lead = () if agents is None else (agents,)
+    shapes = {"router": ((E, Ne), jnp.float32),
+              "router_bias": ((Ne,), jnp.float32),
+              "experts": {k: ((8,) + s, jnp.float32) for k, s in (
+                  ("w_gate", (E, F)), ("w_up", (E, F)),
+                  ("w_down", (F, E)))},
+              "shared": {k: (s, jnp.float32) for k, s in (
+                  ("w_gate", (E, 2 * F)), ("w_up", (E, 2 * F)),
+                  ("w_down", (2 * F, E)))}}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(lead + s[0], s[1],
+                                       sharding=one_chip), shapes,
+        is_leaf=lambda s: isinstance(s, tuple))
+    x = jax.ShapeDtypeStruct(lead + (1, 2048, E), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        out, _, stats = moe_apply(cfg, p, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2), stats
+    grad = jax.grad(loss, has_aux=True)
+    return jax.jit(grad if agents is None else jax.vmap(grad)).lower(
+        params, x)
+
+
+def test_held_experts_layer(one_chip):
+    """The held experts' dropless grouped products are the TPU's
+    ragged-dot kernel."""
+    hlo = _held_experts_grad(one_chip).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_held_experts_take_no_agent_axis(one_chip):
+    """The TPU compiler takes a ragged dot without batch dimensions
+    only, so the layer under ``vmap``, even over one agent, does not
+    compile: the train step runs a device's one agent unbatched
+    (``core/sharded_ddal._per_agent_map``)."""
+    with pytest.raises(Exception, match="number of batch dimensions"):
+        _held_experts_grad(one_chip, agents=1).compile()
