@@ -75,10 +75,14 @@ One agent per device: with a ``mesh`` whose agent axes
 agent, or a few agents each, the per-agent pieces — the forward and
 backward pass and the window sketch — run under ``shard_map`` on the
 agents' own devices, each device seeing only its agents. Nothing of
-one agent's pass then crosses a device, and a kernel inside (the
-sketch's Pallas call, the expert layer's grouped product) sees one
-device's arrays. A device that holds one agent runs it unbatched.
-Without a mesh every agent runs under ``vmap`` on one device.
+one agent's forward and backward then crosses a device, and a kernel
+inside (the sketch's Pallas call, the expert layer's grouped product)
+sees one device's arrays. A device that holds one agent runs it
+unbatched. Without a mesh every agent runs under ``vmap`` on one
+device. The window sketch's signs depend on position alone, so under
+the mesh each device hashes a share of every large leaf's positions
+for all agents, and the rows' sums are scattered back to their
+devices (``repro.kernels.grad_sketch.ops.sketch_pytree``).
 
 Device scopes: the train step names each of its pieces with
 ``jax.named_scope`` — ``ddal.grad`` (the agents' forward and

@@ -21,8 +21,22 @@ Small leaves (< one kernel tile) always take the jnp reference — the
 launch overhead would dominate and XLA fuses them anyway. Leaves and
 sketch dims that don't meet the kernel's lane alignment (d % 128)
 fall back to the tiled XLA path rather than failing.
+
+Under ``shard_map`` the rows of each device are its own (its agents'),
+and every device would hash the same signs: they depend on position,
+not on row. So where the caller's ``shard_map`` spans D > 1 devices,
+``sketch_pytree`` divides each kernel-sized leaf's positions instead.
+An ``all_to_all`` hands device q the q-th contiguous share of every
+device's rows, the kernel sketches those L·D rows over that share
+with the share's start folded into the seed scalar (the hash's input
+is ``seed + position·P1 + j·P2``, wrapping, so the signs are the same
+bits), and one ``psum_scatter`` returns each device the sum of its own
+rows' shares. Each device hashes 1/D of the signs; the result is the
+per-device sketch up to float32 summation order.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +45,7 @@ from repro.kernels.grad_sketch import ref
 from repro.kernels.grad_sketch.kernel import (
     DEFAULT_ROWS,
     LANES,
+    _hash_offset,
     sign_block_i8,
     sketch_flat,
 )
@@ -115,18 +130,63 @@ def sketch_leaf(x: jnp.ndarray, seed, dim: int, offset: int = 0, *,
     return kernel(G)
 
 
+def _manual_axes():
+    """The mesh axes the caller's ``shard_map`` runs manually over, in
+    mesh order, and the number of devices they span (1 outside one)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = tuple(mesh.manual_axes)
+    return axes, math.prod(mesh.shape[a] for a in axes)
+
+
+def _sketch_share(x, seed, dim: int, offset: int, axes, devices: int,
+                  impl: str) -> jnp.ndarray:
+    """This device's (n·D, d) partial sketch of one leaf (n, *param)
+    under ``shard_map`` over ``axes``: the all-to-all gives it the
+    q-th of D contiguous shares of the positions of every device's n
+    rows, device by device, and it sketches them with the share's
+    start folded into the seed. The split runs along the leading
+    parameter axis where that divides by D (no copy of the leaf);
+    otherwise the flat leaf is padded with zero positions, which add
+    nothing to G·S."""
+    n = x.shape[0]
+    if x.ndim < 2 or x.shape[1] % devices:
+        p = int(x.size) // n
+        x = jnp.pad(jnp.reshape(x, (n, p)), ((0, 0), (0, -p % devices)))
+    part = jax.lax.all_to_all(x, axes, 1, 0, tiled=True)
+    share = int(part.size) // (n * devices)
+    start = (jnp.uint32(offset % 2**32)
+             + jax.lax.axis_index(axes).astype(jnp.uint32)
+             * jnp.uint32(share))
+    seed_q = jax.lax.bitcast_convert_type(_hash_offset(seed, start),
+                                          jnp.int32)
+    return sketch_leaf(part, seed_q, dim, impl=impl)
+
+
 def sketch_pytree(grads, seed, dim: int, *,
                   impl: str = "auto") -> jnp.ndarray:
     """Stream a stacked gradient pytree into its (n, d) sketch in one
     pass — the (n, P) concat is never built. ``seed`` may be traced;
-    the sketch is a deterministic pure function of (seed, grads)."""
+    the sketch is a deterministic pure function of (seed, grads).
+    Under a ``shard_map`` over D > 1 devices each device gets its own
+    rows' sketch, and a leaf whose D-th share fills a kernel tile is
+    hashed a share a device (module docstring)."""
     leaves = jax.tree.leaves(grads)
     if not leaves:
         raise ValueError("sketch_pytree needs at least one leaf")
     n = leaves[0].shape[0]
+    axes, devices = _manual_axes()
     acc = jnp.zeros((n, dim), jnp.float32)
+    shared = None
     offset = 0
     for x in leaves:
-        acc = acc + sketch_leaf(x, seed, dim, offset, impl=impl)
-        offset += int(x.size) // n
+        p = int(x.size) // n
+        if devices > 1 and -(-p // devices) >= _MIN_KERNEL_SIZE:
+            part = _sketch_share(x, seed, dim, offset, axes, devices,
+                                 impl)
+            shared = part if shared is None else shared + part
+        else:
+            acc = acc + sketch_leaf(x, seed, dim, offset, impl=impl)
+        offset += p
+    if shared is not None:
+        acc = acc + jax.lax.psum_scatter(shared, axes, tiled=True)
     return acc

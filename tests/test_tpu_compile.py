@@ -14,6 +14,7 @@ imports every test file.
 """
 from __future__ import annotations
 
+import math
 import os
 
 import jax
@@ -32,14 +33,18 @@ Q_BLOCK = 1024               # int8 scale block
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:                          # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -92,6 +97,47 @@ def test_sketch_flat(one_chip, p):
     _compile(one_chip,
              lambda G, seed: sketch_flat(G, seed, 256, interpret=False),
              ((2, p), jnp.float32), ((), jnp.int32))
+
+
+# kanana-2-30b-a3b's expert leaf (4 layers × 8 held experts × 2,048 ×
+# 768) split over a 2x2 host: each chip sketches a quarter of it for
+# all four agents
+EXPERT_LEAF = (4, 8, 2_048, 768)
+
+
+def test_sketch_flat_every_agents_quarter(one_chip):
+    _compile(one_chip,
+             lambda G, seed: sketch_flat(G, seed, 256, interpret=False),
+             ((4, math.prod(EXPERT_LEAF) // 4), jnp.float32),
+             ((), jnp.int32))
+
+
+def test_split_sketch_on_a_2x2_mesh(topo):
+    """The window sketch of one agent a chip under ``shard_map`` on the
+    described (pod 2, agent 2) mesh, over the embedding and an expert
+    leaf: the all-to-all, the kernel on every agent's share of the
+    positions, and the reduce-scatter that returns each chip its
+    agent's row (at 4 KiB the compiler makes it an all-reduce and a
+    slice)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.kernels.grad_sketch.ops import sketch_pytree
+    from repro.launch.mesh import make_pod_mesh
+
+    mesh = make_pod_mesh(2, devices=topo.devices)
+    axes = mesh.axis_names
+    grads = {k: jax.ShapeDtypeStruct((4,) + s, jnp.float32,
+                                     sharding=NamedSharding(mesh, P(axes)))
+             for k, s in (("embed", (16_032, 2_048)),
+                          ("experts", EXPERT_LEAF))}
+    seed = jax.ShapeDtypeStruct((), jnp.int32,
+                                sharding=NamedSharding(mesh, P()))
+    sketch = jax.shard_map(lambda g, s: sketch_pytree(g, s, 256),
+                           mesh=mesh, in_specs=(P(axes), P()),
+                           out_specs=P(axes), check_vma=False)
+    hlo = jax.jit(sketch).lower(grads, seed).compile().as_text()
+    assert "all-to-all" in hlo and "tpu_custom_call" in hlo
+    assert "reduce-scatter" in hlo or "all-reduce" in hlo
 
 
 def test_flash_attention_bhsd(one_chip):
